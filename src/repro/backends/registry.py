@@ -61,6 +61,10 @@ class HardwareSpec:
                    self.collective_s(ici_bytes))
 
 
+# Peaks per chip from Google Cloud's "TPU v5e" documentation: 197 TFLOP/s
+# bf16, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of interconnect (four links
+# of 50 GB/s).  128 MiB is the physical VMEM; kernels compile under the
+# smaller scoped budget ``kernels._util.VMEM_LIMIT_BYTES``.
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     peak_flops_bf16=197e12,
@@ -69,6 +73,10 @@ TPU_V5E = HardwareSpec(
     hbm_bytes=16 * 1024 ** 3,
     vmem_bytes=128 * 1024 ** 2,
 )
+
+# The chips ``pallas_tpu`` knows, keyed by ``jax.Device.device_kind``.  A
+# kind missing here is an error, never a silent v5e default.
+TPU_SPECS: Dict[str, HardwareSpec] = {"TPU v5 lite": TPU_V5E}
 
 HOST_CPU = HardwareSpec(
     name="host_cpu",
@@ -438,7 +446,40 @@ def register_backend(b: Backend) -> Backend:
     return b
 
 
+def tpu_spec(device_kind: str) -> HardwareSpec:
+    """The :class:`HardwareSpec` of one TPU generation, by device kind."""
+    if device_kind not in TPU_SPECS:
+        raise ValueError(f"no HardwareSpec for device kind {device_kind!r}; "
+                         f"known TPU kinds: {sorted(TPU_SPECS)}")
+    return TPU_SPECS[device_kind]
+
+
+def tpu_backend(device_kind: str) -> Backend:
+    """The real-TPU backend: the Pallas kernels compiled for the chip named
+    by ``device_kind``, with that chip's hardware spec."""
+    return Backend(
+        name="pallas_tpu",
+        interpret=False,
+        hw=tpu_spec(device_kind),
+        linear_weight_layout="io",
+        conv_layout="nhwc",
+        capabilities=frozenset({"xla", "pallas", "mxu"}),
+    )
+
+
 def get_backend(name: str) -> Backend:
+    """A registered backend by name.  ``pallas_tpu`` is built on first use
+    from the local device's kind (an unknown kind raises), and
+    ``pallas_interpret`` is refused on a TPU host, where it would run every
+    kernel in the interpreter."""
+    if name in ("pallas_tpu", "pallas_interpret"):
+        import jax
+        platform = jax.default_backend()
+        if name == "pallas_interpret" and platform == "tpu":
+            raise ValueError("pallas_interpret runs the Pallas kernels in "
+                             "interpret mode; on a TPU select 'pallas_tpu'")
+        if name == "pallas_tpu" and name not in _REGISTRY:
+            register_backend(tpu_backend(jax.devices()[0].device_kind))
     if name not in _REGISTRY:
         raise KeyError(f"unknown backend {name!r}; have {sorted(_REGISTRY)}")
     return _REGISTRY[name]
@@ -482,12 +523,5 @@ register_backend(Backend(
     capabilities=frozenset({"xla", "pallas", "mxu"}),
 ))
 
-# Real-TPU backend: same kernels, compiled.
-register_backend(Backend(
-    name="pallas_tpu",
-    interpret=False,
-    hw=TPU_V5E,
-    linear_weight_layout="io",
-    conv_layout="nhwc",
-    capabilities=frozenset({"xla", "pallas", "mxu"}),
-))
+# Real-TPU backend: same kernels, compiled — registered by ``get_backend``
+# on first use, once the local chip's kind is known (``tpu_backend``).

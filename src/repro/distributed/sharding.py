@@ -35,22 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..models import backbone as B
 from ..models.config import ArchConfig
 
-# jax moved shard_map out of experimental (>=0.6) and renamed check_rep →
-# check_vma, on independent schedules — detect the kwarg from the signature
-# rather than inferring it from where shard_map lives
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                    # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map
-try:
-    import inspect as _inspect
-    _sm_params = _inspect.signature(shard_map).parameters
-    SHARD_MAP_NOCHECK = ({"check_vma": False} if "check_vma" in _sm_params
-                         else {"check_rep": False} if "check_rep" in _sm_params
-                         else {})
-except (TypeError, ValueError):          # unintrospectable wrapper
-    SHARD_MAP_NOCHECK = {}
-
 
 class ShardingError(ValueError):
     """A graph cannot be partitioned as requested (a sharded dim reaches an

@@ -26,14 +26,23 @@ from ..runtime import packed as P
 
 @dataclasses.dataclass
 class _DeviceState:
-    kind: str = "cpu"
+    kind: Optional[str] = None  # JAX platform; None = the default backend
     index: int = 0
     mode: str = "native"       # 'native' | 'transparent'
 
     @property
     def jax_device(self):
-        devs = jax.devices()
-        return devs[min(self.index, len(devs) - 1)]
+        """The selected device; a kind or index this host does not have
+        raises rather than falling back to another device."""
+        try:
+            devs = jax.devices(self.kind)
+        except RuntimeError as e:
+            raise ValueError(f"no {self.kind!r} devices on this host: {e}"
+                             ) from None
+        if not 0 <= self.index < len(devs):
+            raise ValueError(f"device index {self.index} out of range: "
+                             f"{len(devs)} {devs[0].platform} device(s)")
+        return devs[self.index]
 
 
 class _DeviceAPI:
@@ -44,9 +53,11 @@ class _DeviceAPI:
         self.transfer_stats = {"staged_params": 0, "packed_transfers": 0,
                                "direct_transfers": 0}
 
-    def set(self, kind: str, index: int = 0, *,
+    def set(self, kind: Optional[str], index: int = 0, *,
             mode: str = "transparent") -> None:
-        self.state = _DeviceState(kind, index, mode)
+        state = _DeviceState(kind, index, mode)
+        state.jax_device                 # validate before switching
+        self.state = state
 
     def stage_params(self, params: Dict[str, Any]) -> Dict[str, Any]:
         dev = self.state.jax_device

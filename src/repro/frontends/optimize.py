@@ -201,8 +201,8 @@ def compile_graph(model: nn.Module, graph, backend: str | Backend = "xla",
     raw_fn = lower_graph(graph, bk, differentiable=training)
     out_specs = (graph.output_specs[0] if len(graph.output_specs) == 1
                  else tuple(graph.output_specs))
-    sharded = shd.shard_map(
+    sharded = jax.shard_map(
         raw_fn, mesh=mesh,
         in_specs=(dict(graph.param_specs), *graph.input_specs),
-        out_specs=out_specs, **shd.SHARD_MAP_NOCHECK)
+        out_specs=out_specs, check_vma=False)
     return SolModel(model, graph, bk, jax.jit(sharded), mesh=mesh)

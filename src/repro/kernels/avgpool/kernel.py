@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .._util import tpu_params
+
 
 def _kernel(kh: int, kw: int, out_h: int, out_w: int, x_ref, o_ref):
     bc = x_ref.shape[1]
@@ -45,5 +47,6 @@ def avgpool_call(x: jax.Array, kh: int = 3, kw: int = 3, *,
         out_specs=pl.BlockSpec((1, bc, out_h, out_w),
                                lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, c, out_h, out_w), x.dtype),
+        compiler_params=tpu_params("parallel", "parallel"),
         interpret=interpret,
     )(x)

@@ -19,12 +19,13 @@ import functools
 from typing import List, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from ...backends import registry
 from ...core.autotune import Tunable
 from ...core.ir import Node, OpKind
-from .._util import round_up
-from .kernel import DEFAULT_BK, decode_attention_call
+from .._util import VMEM_LIMIT_BYTES, round_up
+from .kernel import DEFAULT_BK, decode_attention_call, vmem_bytes
 
 
 @functools.partial(jax.jit, static_argnames=("window", "cap", "bk",
@@ -49,24 +50,37 @@ def _attrs(n: Node) -> dict:
                 cap=n.attrs.get("cap", 0.0))
 
 
+def _fits(n: Node, bk: int) -> bool:
+    """Whether one grid step at kv block ``bk`` fits the VMEM budget the
+    kernel compiles under (the whole per-(batch, kv head) cache is
+    resident)."""
+    _, s, kv, hd = n.inputs[1].spec.shape      # k_cache is (B, S, KV, hd)
+    bk = min(bk, round_up(s, 8))
+    group = n.spec.shape[2] // kv
+    return vmem_bytes(round_up(s, bk), hd, group, bk,
+                      jnp.dtype(n.spec.dtype).itemsize) <= VMEM_LIMIT_BYTES
+
+
+def _supports(n: Node) -> bool:
+    return (len(n.spec.shape) == 4 and len(n.inputs) == 6
+            and len(n.inputs[1].spec.shape) == 4 and _fits(n, DEFAULT_BK))
+
+
 def decode_tune_space(n: Node, hw) -> List[Tuple[int]]:
     """Candidate kv block lengths for one DECODE_ATTENTION node: powers of
     two up to the default block, clamped to the (8-sublane rounded) cache
     bucket length, deduplicated, and gated on the whole per-head cache plus
-    the block-sized working set fitting in half of VMEM."""
+    the block-sized working set fitting the kernels' VMEM budget."""
     if len(n.inputs) < 2 or len(n.inputs[1].spec.shape) != 4:
         return []
-    s = n.inputs[1].spec.shape[1]              # k_cache is (B, S, KV, hd)
-    hd = n.spec.shape[-1]
+    s = n.inputs[1].spec.shape[1]
     cap = round_up(s, 8)
     cands: List[Tuple[int]] = []
     seen = set()
     size = 32
     while size <= DEFAULT_BK:
         bk = min(size, cap)
-        # cache k+v (sp, hd) f32 per kv head + kv block + logits row
-        working = 4 * (2 * round_up(s, bk) * hd + 2 * bk * hd + 2 * bk)
-        if bk not in seen and working <= hw.vmem_bytes // 2:
+        if bk not in seen and _fits(n, bk):
             seen.add(bk)
             cands.append((bk,))
         size *= 2
@@ -95,7 +109,7 @@ def _decode_attention_ref_impl(n: Node, vals: Sequence[jax.Array],
 registry.register_shared_impl(
     OpKind.DECODE_ATTENTION, _decode_attention_pallas_impl,
     name="pallas.decode_attention", requires=("pallas",),
-    supports=lambda n: len(n.spec.shape) == 4,
+    supports=_supports,
     tunable=Tunable("decode_block", decode_tune_space))
 registry.register_reference_impl(
     OpKind.DECODE_ATTENTION, _decode_attention_ref_impl,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .._util import tpu_params
 from .program import Program
 
 # VMEM working-set budget per block (bytes); conservative vs 128 MiB/core so
@@ -147,6 +148,7 @@ def dfp_fused_call(prog: Program, operands: Sequence[jax.Array],
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((rows, d), out_dtype),
+        compiler_params=tpu_params("parallel"),
         interpret=interpret,
     )(*full_ops, *vec_ops)
     return out.reshape(out_shape)

@@ -20,6 +20,7 @@ import jax
 from ...backends import registry
 from ...core.autotune import Tunable
 from ...core.ir import Node, OpKind
+from .._util import VMEM_LIMIT_BYTES
 from .kernel import choose_block_rows, clamp_block_rows, dfp_fused_call
 from .program import Program, split_program
 
@@ -68,7 +69,9 @@ def _supports_chain(n: Node) -> bool:
 def dfp_tune_space(n: Node, hw) -> List[Tuple[int, int]]:
     """Candidate (block_rows, max_group) configs for one FUSED node: the
     VMEM-budget heuristic row block plus coarser/finer power-of-two blocks
-    (clamped and VMEM-gated for the body's register count), crossed with the
+    (clamped and gated for the body's register count on half the kernels'
+    VMEM budget, the other half left to the pipeline's double-buffered
+    input and output blocks), crossed with the
     whole chain vs a half-length fusion split when the body is long enough
     to have split points worth measuring."""
     shape = n.spec.shape
@@ -84,7 +87,7 @@ def dfp_tune_space(n: Node, hw) -> List[Tuple[int, int]]:
     brs = sorted({clamp_block_rows(c, rows)
                   for c in (auto, 128, 512, 2048)
                   if n_regs * clamp_block_rows(c, rows) * max(d, 128) * 4
-                  <= hw.vmem_bytes // 2})
+                  <= VMEM_LIMIT_BYTES // 2})
     groups = [len(body)]
     if len(body) >= 4:
         groups.append((len(body) + 1) // 2)

@@ -3,10 +3,14 @@ logit softcap — the DNN-module flavour of the chunked online-softmax scan in
 ``models.layers``.
 
 Grid: (batch, q_head, q_block).  The kv-head index is derived from the
-q-head index (GQA: h // group).  K/V for one kv head live in VMEM whole
-(S·hd·2 B ≤ 8 MiB at 32k×128 bf16); the kernel streams kv blocks out of
-them with an online-softmax carry in VREGs.  Causality bounds the kv loop
-dynamically — upper = ceil((q_hi+1)/bk) — so the wasted-block count is zero.
+q-head index (GQA: h // group).  K/V for one kv head live in VMEM whole,
+and the pipeline double-buffers them: ``4·S·hd·itemsize`` bytes, 32 MiB at
+S=16k, hd=128 in f32 and 64 MiB at 32k.  :func:`vmem_bytes` is the budget
+the ``supports`` predicate checks against the limit the kernel compiles
+under, so longer sequences elect the reference.  The kernel streams kv
+blocks out of them with an online-softmax carry in VREGs.  Causality bounds
+the kv loop dynamically — upper = ceil((q_hi+1)/bk) — so the wasted-block
+count is zero.
 
 BlockSpecs:
   q:   (1, 1, bq, hd)   index (b, h, i) -> (b, h, i, 0)
@@ -24,10 +28,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .._util import round_up as _round_up
+from .._util import tpu_params
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
 NEG = -1e30
+
+
+def blocks(s: int, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK
+           ) -> Tuple[int, int, int]:
+    """The (bq, bk, padded S) a call with these requested blocks runs."""
+    bq = min(bq, _round_up(s, 8))     # keep the 8-sublane alignment for
+    bk = min(bk, _round_up(s, 8))     # short sequences instead of bq = s
+    if max(bq, bk) % min(bq, bk):     # incommensurate pair: collapse to the
+        bq = bk = min(bq, bk)         # smaller instead of an lcm-sized pad
+    return bq, bk, _round_up(s, max(bq, bk))   # padded S divides both
+
+
+def vmem_bytes(s: int, hd: int, bq: int, bk: int, itemsize: int) -> int:
+    """VMEM one grid step needs: double-buffered whole-sequence K/V and
+    q/o blocks, plus the f32 logits tile, accumulator and kv blocks."""
+    bq, bk, sp = blocks(s, bq, bk)
+    return (4 * sp * hd * itemsize + 4 * bq * hd * itemsize
+            + 4 * (2 * bq * bk + bq * hd + 2 * bk * hd))
 
 
 def _kernel(bq: int, bk: int, causal: bool, window: int, cap: float,
@@ -95,12 +118,7 @@ def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, h, s, hd = q.shape
     kv = k.shape[1]
     group = h // kv
-    bq = min(bq, _round_up(s, 8))     # keep the 8-sublane alignment for
-    bk = min(bk, _round_up(s, 8))     # short sequences instead of bq = s
-    if max(bq, bk) % min(bq, bk):     # incommensurate pair: collapse to the
-        bq = bk = min(bq, bk)         # smaller instead of an lcm-sized pad
-    step = max(bq, bk)                # padded S must divide both blocks
-    sp = _round_up(s, step)
+    bq, bk, sp = blocks(s, bq, bk)
     if sp != s:
         pad = ((0, 0), (0, 0), (0, sp - s), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
@@ -120,6 +138,7 @@ def flash_attention_call(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda b_, h_, i: (b_, h_, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sp, hd), q.dtype),
+        compiler_params=tpu_params("parallel", "parallel", "parallel"),
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :s, :] if sp != s else out

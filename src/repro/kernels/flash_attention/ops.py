@@ -15,8 +15,8 @@ import jax.numpy as jnp
 from ...backends import registry
 from ...core.autotune import Tunable
 from ...core.ir import Node, OpKind
-from .._util import round_up
-from .kernel import DEFAULT_BK, DEFAULT_BQ, flash_attention_call
+from .._util import VMEM_LIMIT_BYTES, round_up
+from .kernel import DEFAULT_BK, DEFAULT_BQ, flash_attention_call, vmem_bytes
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "cap",
@@ -42,11 +42,24 @@ def _attrs(n: Node) -> dict:
                 cap=n.attrs.get("cap", 0.0))
 
 
+def _fits(n: Node, bq: int, bk: int) -> bool:
+    """Whether one grid step at blocks (bq, bk) — whole-sequence K/V
+    resident — fits the VMEM budget the kernel compiles under."""
+    _, s, _, hd = n.spec.shape
+    return vmem_bytes(s, hd, bq, bk,
+                      jnp.dtype(n.spec.dtype).itemsize) <= VMEM_LIMIT_BYTES
+
+
+def _supports(n: Node) -> bool:
+    return len(n.spec.shape) == 4 and _fits(n, DEFAULT_BQ, DEFAULT_BK)
+
+
 def attn_tune_space(n: Node, hw) -> List[Tuple[int, int]]:
     """Candidate (bq, bk) block pairs for one ATTENTION node: powers of two
     from one VPU row block up to the default block, clamped to the (8-sublane
-    rounded) sequence length, deduplicated, and gated on the f32 logits tile
-    plus the q/k/v/accumulator blocks fitting in half of VMEM."""
+    rounded) sequence length, deduplicated, and gated on the resident K/V,
+    the f32 logits tile and the q/accumulator blocks fitting the kernels'
+    VMEM budget."""
     b, s, h, hd = n.spec.shape
     cap = min(DEFAULT_BQ, round_up(s, 8))
     cands: List[Tuple[int, int]] = []
@@ -59,10 +72,7 @@ def attn_tune_space(n: Node, hw) -> List[Tuple[int, int]]:
     for bq in sizes:
         for bk in sizes:
             cfg = (min(bq, cap), min(bk, cap))
-            # logits/mask (bq, bk) f32 + q/acc (bq, hd) + k/v blocks (bk, hd)
-            working = 4 * (2 * cfg[0] * cfg[1]
-                           + 2 * cfg[0] * hd + 2 * cfg[1] * hd)
-            if cfg in seen or working > hw.vmem_bytes // 2:
+            if cfg in seen or not _fits(n, *cfg):
                 continue
             seen.add(cfg)
             cands.append(cfg)
@@ -89,8 +99,7 @@ def _attention_ref_impl(n: Node, vals: Sequence[jax.Array],
 
 registry.register_shared_impl(
     OpKind.ATTENTION, _attention_pallas_impl, name="pallas.flash_attention",
-    requires=("pallas",),
-    supports=lambda n: len(n.spec.shape) == 4,
+    requires=("pallas",), supports=_supports,
     tunable=Tunable("attn_block", attn_tune_space))
 registry.register_reference_impl(
     OpKind.ATTENTION, _attention_ref_impl, name="ref.attention",

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .._util import VMEM_LIMIT_BYTES, tpu_params
 from .._util import round_up as _round_up
 
 Block = Tuple[int, int, int]          # (bm, bk, bn)
@@ -50,7 +51,8 @@ def default_block(m: int, k: int, n: int, mxu_dim: int = 128) -> Block:
 def tile_space(m: int, k: int, n: int, hw) -> List[Block]:
     """The autotune search space: {1,2,4}·mxu_dim output tiles × {1,2}·mxu_dim
     K depth, deduplicated after clamping and gated on the working set
-    (x tile + w tile + f32 accumulator) fitting in half of VMEM."""
+    (double-buffered x, w and output tiles + the f32 accumulator) fitting
+    the VMEM budget the kernel compiles under."""
     d = hw.mxu_dim
     out: List[Block] = []
     seen = set()
@@ -59,8 +61,9 @@ def tile_space(m: int, k: int, n: int, hw) -> List[Block]:
             for kk in (1, 2):
                 blk = _clamp((mm * d, kk * d, nn * d), m, k, n)
                 bm, bk, bn = blk
-                working_set = 4 * (bm * bk + bk * bn) + 4 * 2 * bm * bn
-                if working_set > hw.vmem_bytes // 2 or blk in seen:
+                working_set = 4 * 2 * (bm * bk + bk * bn + bm * bn) \
+                    + 4 * bm * bn
+                if working_set > VMEM_LIMIT_BYTES or blk in seen:
                     continue
                 seen.add(blk)
                 out.append(blk)
@@ -107,6 +110,7 @@ def matmul_call(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kq: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=tpu_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x, w)
     return out[:m, :n] if (mp, np_) != (m, n) else out

@@ -24,15 +24,18 @@ def rglru_scan(a: jax.Array, b: jax.Array, h0: jax.Array, *,
 #    the graph-level op yields the full hidden sequence h.
 
 def _clamp_bd(bd: int, d: int) -> int:
-    """The kernel's channel block must divide D: gcd is the largest value
-    that both divides D and never exceeds the request."""
-    return math.gcd(max(1, int(bd)), d)
+    """The kernel's channel block must divide D, and to tile on the TPU be
+    a multiple of 128 lanes or D itself: gcd is the largest value that both
+    divides D and never exceeds the request, and a divisor off the lane
+    grid widens to the whole of D."""
+    bd = math.gcd(max(1, int(bd)), d)
+    return bd if bd % 128 == 0 else d
 
 
 def rglru_tune_space(n: Node, hw) -> List[Tuple[int]]:
     """Candidate channel-block lengths for one RGLRU_SCAN node: VPU-lane
     multiples up to the default block plus the whole/half channel dim, each
-    clamped to a divisor of D and deduplicated."""
+    clamped to a lane-aligned divisor of D and deduplicated."""
     if len(n.spec.shape) != 3:
         return []
     d = n.spec.shape[-1]

@@ -31,13 +31,14 @@ def rwkv6_scan(r, k, v, logw, u, s0, *, bt: int = 0,
 
 def rwkv6_tune_space(n: Node, hw) -> List[Tuple[int]]:
     """Candidate time-block lengths: sublane multiples up to the whole
-    sequence, clamped to divisors of T (gcd) and deduplicated."""
+    sequence, clamped to divisors of T (gcd) and deduplicated; a block off
+    the 8-sublane grid is kept only when it is the whole of T."""
     if len(n.spec.shape) != 4:
         return []
     t = n.spec.shape[1]
     cands = {math.gcd(v, t) for v in (hw.sublanes, 4 * hw.sublanes,
                                       16 * hw.sublanes, t, max(1, t // 2))}
-    return [(bt,) for bt in sorted(cands)]
+    return [(bt,) for bt in sorted(cands) if bt % 8 == 0 or bt == t]
 
 
 def rwkv6_refine_space(n: Node, hw, cfg) -> List[Tuple[int]]:
@@ -45,7 +46,8 @@ def rwkv6_refine_space(n: Node, hw, cfg) -> List[Tuple[int]]:
     divisor-clamped half/double steps around the winner."""
     t = n.spec.shape[1]
     bt = int(cfg[0])
-    return [(math.gcd(max(1, c), t),) for c in (bt // 2, bt * 2, bt * 4)]
+    cands = (math.gcd(max(1, c), t) for c in (bt // 2, bt * 2, bt * 4))
+    return [(c,) for c in cands if c % 8 == 0 or c == t]
 
 
 def _rwkv6_pallas_impl(n: Node, vals: Sequence[jax.Array],

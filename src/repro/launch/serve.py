@@ -79,6 +79,7 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -92,6 +93,7 @@ from ..frontends.optimize import (SolModel, compile_graph, optimize,
                                   provenance_violations)
 from ..runtime import packed
 from ..runtime.async_queue import AsyncQueue
+from .compile_cache import use_compile_cache
 
 TOKEN_BYTES = 4                    # int32 tokens in the slot arena
 KV_BYTES = 4                       # float32 cache rows in the slot arena
@@ -907,16 +909,16 @@ def _measure_node(node, backend, cache: AT.AutotuneCache, *,
     Integer inputs (the decode program's ``lens``) get worst-case values:
     every row attends a full cache, so the recorded timing bounds the
     served cost."""
-    rng = np.random.default_rng(0)
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), len(node.inputs)))
     vals = []
     for inp in node.inputs:
+        key = next(keys)
         if inp.spec.dtype.startswith("int"):
             fill = (node.inputs[1].spec.shape[1]
                     if node.op is OpKind.DECODE_ATTENTION else 1)
             vals.append(jnp.full(inp.spec.shape, fill, jnp.int32))
-        else:
-            vals.append(jnp.asarray(rng.standard_normal(inp.spec.shape),
-                                    jnp.float32))
+        else:       # drawn where they are used: no host RNG, no transfer
+            vals.append(jax.random.normal(key, inp.spec.shape, jnp.float32))
     return len(measure.sweep_node(node, vals, backend, cache,
                                   warmup=warmup, iters=iters))
 
@@ -1034,6 +1036,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--no-deploy-roundtrip", action="store_true",
                     help="skip the artifact round-trip leg of --smoke")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     try:
         mesh = tuple(int(a) for a in args.mesh.split(","))

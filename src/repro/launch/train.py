@@ -37,6 +37,7 @@ from ..distributed.steps import (StepOptions, init_train_state,
                                  make_train_step)
 from ..models import backbone as B
 from ..runtime import StragglerMonitor
+from .compile_cache import use_compile_cache
 from .mesh import make_debug_mesh, make_production_mesh
 
 
@@ -102,11 +103,14 @@ def _sol_main(args) -> None:
     batch = min(args.batch, 4) if args.smoke else args.batch
     model = _sol_zoo_model(args.sol_model, d_model)
     shape = (batch, seq, d_model)
+    backend = args.sol_backend or (
+        "pallas_tpu" if jax.default_backend() == "tpu"
+        else "pallas_interpret")
 
     # cold compile → warm the cache on the real nodes → re-elect measured
-    sm = optimize(model, shape, backend=args.sol_backend, training=True)
+    sm = optimize(model, shape, backend=backend, training=True)
     swept = _warm_autotune(sm.graph, sm.backend)
-    sm = optimize(model, shape, backend=args.sol_backend, training=True)
+    sm = optimize(model, shape, backend=backend, training=True)
     by_kind = sm.impl_report(by_kind=True)
     print(f"[train --sol] warmed {swept} node buckets; elections:")
     for kind, impls in sorted(by_kind.items()):
@@ -170,7 +174,9 @@ def main() -> None:
     ap.add_argument("--sol-model", default="transformer",
                     help="model-zoo block for --sol "
                          "(transformer|griffin|rwkv6)")
-    ap.add_argument("--sol-backend", default="pallas_interpret")
+    ap.add_argument("--sol-backend", default=None,
+                    help="default: pallas_tpu on a TPU, pallas_interpret "
+                         "elsewhere")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -185,6 +191,7 @@ def main() -> None:
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.sol:
         _sol_main(args)
@@ -210,16 +217,14 @@ def main() -> None:
     ckpt = CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval)
     monitor = StragglerMonitor(n_hosts=1)
 
-    # resume if a checkpoint exists
+    # resume if a checkpoint exists; a checkpoint that fails to restore
+    # is an error, not a fresh start
     start = 0
-    try:
-        restored_step, restored = ckpt.restore_latest(
-            jax.eval_shape(lambda: state))
-        if restored is not None:
-            state, start = restored, restored_step
-            print(f"[train] resumed from step {start}")
-    except Exception:
-        pass
+    restored_step, restored = ckpt.restore_latest(
+        jax.eval_shape(lambda: state))
+    if restored is not None:
+        state, start = restored, restored_step
+        print(f"[train] resumed from step {start}")
 
     loader = DataLoader(dataset, start_step=start)
     jitted = jax.jit(step_fn, donate_argnums=(0,))

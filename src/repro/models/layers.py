@@ -15,22 +15,6 @@ import jax.numpy as jnp
 
 Array = jax.Array
 
-# jax moved shard_map out of experimental (>=0.6) and renamed check_rep →
-# check_vma, on independent schedules — detect the kwarg from the signature
-# rather than inferring it from where shard_map lives
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                    # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-try:
-    import inspect as _inspect
-    _sm_params = _inspect.signature(_shard_map).parameters
-    _SHARD_MAP_NOCHECK = ({"check_vma": False} if "check_vma" in _sm_params
-                          else {"check_rep": False} if "check_rep" in _sm_params
-                          else {})
-except (TypeError, ValueError):          # unintrospectable wrapper
-    _SHARD_MAP_NOCHECK = {}
-
 # attention chunk size for the flash-style scan (queries keep full length,
 # keys/values stream in chunks; online softmax carries m/l/acc)
 ATTN_CHUNK = 2048
@@ -346,11 +330,11 @@ def _moe_apply_shard_map(p, x, moe_cfg, mesh) -> Tuple[Array, Array]:
         out = jax.lax.psum(out, "model")          # the combine reduction
         return out[:, :gs].reshape(bl, sl, dl), aux
 
-    out, aux = _shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(w_spec, x_spec),
         out_specs=(x_spec, P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )({k: p[k] for k in ("router", "wg", "wu", "wd")}, x)
     return out, aux
 

@@ -243,6 +243,42 @@ def test_measured_attention_election_pins_and_clears_block():
     assert "attn_block" not in node.attrs
 
 
+def _decode_graph(b=1, s=64, h=2, kv=2, hd=16):
+    ins = [ir.input_node(shape) for shape in
+           ((b, 1, h, hd), (b, s, kv, hd), (b, s, kv, hd), (b, 1, kv, hd),
+            (b, 1, kv, hd))] + [ir.input_node((b,), "int32")]
+    node = Node(OpKind.DECODE_ATTENTION, ins, TensorSpec((b, 1, h, hd)))
+    return Graph(ins, [node], {}), node
+
+
+@pytest.mark.parametrize("build,op,kernel,ref", [
+    (_attention_graph, "attention", "pallas.flash_attention",
+     "ref.attention"),
+    (_decode_graph, "decode_attention", "pallas.decode_attention",
+     "ref.decode_attention"),
+], ids=["flash", "decode"])
+def test_resident_kv_past_vmem_budget_elects_reference(build, op, kernel,
+                                                       ref):
+    """Where the whole-sequence K/V a kernel keeps resident exceeds the VMEM
+    budget it compiles under, its ``supports`` declines and the reference is
+    elected, even against a measurement that favours the kernel.  At
+    hd=128 in f32 the double-buffered K/V of S=32768 alone fill the 64 MiB
+    budget; at S=16384 the kernel still wins."""
+    bk = R.tpu_backend("TPU v5 lite")
+    for seq, want in ((32768, ref), (16384, kernel)):
+        g, node = build(1, seq, 1, 1, 128) if op.startswith("decode") \
+            else build(1, seq, 1, 128)
+        c = AutotuneCache()
+        c.record(op, autotune.node_shape(node), "float32", bk.cache_name,
+                 kernel, 1.0)
+        c.record(op, autotune.node_shape(node), "float32", bk.cache_name,
+                 ref, 9.0)
+        autotune.set_cache(c)
+        passes.elect_implementations(g, bk)
+        assert node.impl == want, (seq, node.impl)
+        assert g.elections_by_op[op] == {want: 1}
+
+
 def test_reelection_on_foreign_backend_clears_pin():
     """Re-electing on a backend where the tuned impl is inadmissible (no
     'pallas' capability on host_cpu) must still drop the stale pin."""
@@ -403,7 +439,7 @@ def test_mxu_matmul_elected_and_correct_on_pallas_backends():
     ragged-tail shape)."""
     for b, d_in, d_out in ((2, 128, 128), (3, 100, 65)):
         g, lin = _linear_graph(b, d_in, d_out)
-        passes.elect_implementations(g, get_backend("pallas_tpu"))
+        passes.elect_implementations(g, R.tpu_backend("TPU v5 lite"))
         assert lin.impl == "pallas.linear_mxu", (b, d_in, d_out)
 
         rng = np.random.default_rng(0)

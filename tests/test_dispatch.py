@@ -51,8 +51,9 @@ def test_register_impl_overrides_fallback():
 def test_unregistered_op_falls_back_to_reference():
     """Ops without backend-specific or shared impls resolve to the reference
     tier on every backend — the chain never dead-ends."""
-    for name in ("xla", "host_cpu", "pallas_interpret", "pallas_tpu"):
-        bk = get_backend(name)
+    for bk in [get_backend(name)
+               for name in ("xla", "host_cpu", "pallas_interpret")] \
+            + [R.tpu_backend("TPU v5 lite")]:
         _, node = _relu_graph()
         impl = bk.resolve(node)
         assert impl.tier == R.TIER_REFERENCE
@@ -179,3 +180,24 @@ def test_host_cpu_parity_vs_xla(builder, shape):
         ys[bk] = np.asarray(optimize(model, shape, backend=bk)(x))
     np.testing.assert_allclose(ys["host_cpu"], ys["xla"],
                                rtol=1e-5, atol=1e-5)
+
+
+# -- hardware spec by device kind ----------------------------------------------
+
+def test_pallas_tpu_spec_keyed_by_device_kind():
+    """pallas_tpu takes its HardwareSpec from the device-kind table; an
+    unknown kind (the CPU this suite runs on among them) is an error, not
+    the v5e default."""
+    assert R.tpu_backend("TPU v5 lite").hw is R.TPU_V5E
+    assert not R.tpu_backend("TPU v5 lite").interpret
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        R.tpu_backend("TPU v9 imaginary")
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        get_backend("pallas_tpu")          # this host's device kind is cpu
+
+
+def test_pallas_interpret_refused_on_tpu(monkeypatch):
+    """Interpret mode on a TPU host raises and names pallas_tpu."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="pallas_tpu"):
+        get_backend("pallas_interpret")

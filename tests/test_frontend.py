@@ -71,6 +71,16 @@ def test_transparent_offload_host_roundtrip():
     np.testing.assert_allclose(y, y_ref, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kind,index", [("tpu", 0), ("cpu", 3)])
+def test_device_set_refuses_missing_device(kind, index):
+    """A device the host does not have raises; it is never replaced by
+    another device (the earlier state stays selected)."""
+    before = device.state
+    with pytest.raises(ValueError):
+        device.set(kind, index)
+    assert device.state == before
+
+
 def test_deploy_roundtrip_and_independence():
     model = nn.small_cnn()
     sol = optimize(model, (1, 3, 16, 16))

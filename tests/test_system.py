@@ -117,3 +117,30 @@ def test_checkpoint_resume_training(tmp_path):
         batch = {k: jnp.asarray(v) for k, v in ds.batch(6).items()}
         state2, m2 = jitted(restored, batch)
     assert np.isfinite(float(m2["loss"]))
+
+
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+def test_compile_cache_placed_by_entry_point_helper(monkeypatch, tmp_path,
+                                                    env_set):
+    """With JAX_COMPILATION_CACHE_DIR set the helper leaves JAX to use it and
+    sets no other directory; without it the cache goes to one fixed,
+    git-ignored directory at the root of the checkout."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert CC.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            root = Path(__file__).resolve().parents[1]
+            assert CC.CHECKOUT_CACHE == root / ".jax_cache"
+            assert CC.use_compile_cache() == str(CC.CHECKOUT_CACHE)
+            assert jax.config.jax_compilation_cache_dir == str(
+                CC.CHECKOUT_CACHE)
+            assert ".jax_cache/" in (root / ".gitignore").read_text()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
