@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+device, in %."""
+
+
+def read(run):
+    s = run.summary
+    if not s or s["window_s"] <= 0 or not s["devices"]:
+        return None
+    return 100.0 * (1.0 - s["busy_s"] / s["window_s"])
