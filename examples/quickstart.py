@@ -11,6 +11,7 @@ sys.path.insert(0, "src")
 from repro.frontends import nn
 from repro.frontends.optimize import optimize as sol_optimize
 from repro.frontends.offload import device as sol_device
+from repro.runtime import packed
 
 
 def main() -> None:
@@ -35,7 +36,7 @@ def main() -> None:
     sol_device.set("cpu", 0, mode="transparent")
     y2 = sol_model(x)
     print(f"transparent offload returns host array: {type(y2).__name__}, "
-          f"transfers: {sol_device.transfer_stats}")
+          f"transfers: {packed.TRANSFER_STATS}")
 
 
 if __name__ == "__main__":

@@ -370,8 +370,11 @@ def lower_graph(g: Graph, backend: "registry.Backend", *,
                 raise ValueError(f"unbound source node {n}")
             vals = [env[id(i)] for i in n.inputs]
             call = calls.get(id(n))
-            env[id(n)] = (call(*vals) if call is not None
-                          else impls[id(n)].fn(n, vals, backend))
+            # "op:impl" in the name of every device operation of the node,
+            # so a profile ties each operation to its node and election
+            with jax.named_scope(f"{n.op.value}:{impls[id(n)].name}"):
+                env[id(n)] = (call(*vals) if call is not None
+                              else impls[id(n)].fn(n, vals, backend))
             # row-parallel matmuls under shard_map produce partial sums:
             # shard_graph marks them and the collective lowers here, before
             # any downstream bias add (BIAS_ADD is its own node)

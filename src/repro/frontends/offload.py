@@ -50,8 +50,6 @@ class _DeviceAPI:
 
     def __init__(self):
         self.state = _DeviceState()
-        self.transfer_stats = {"staged_params": 0, "packed_transfers": 0,
-                               "direct_transfers": 0}
 
     def set(self, kind: Optional[str], index: int = 0, *,
             mode: str = "transparent") -> None:
@@ -72,11 +70,8 @@ class _DeviceAPI:
         if small:
             arrs = P.transfer([np.asarray(params[k]) for k in small], dev)
             out.update(dict(zip(small, arrs)))
-            self.transfer_stats["packed_transfers"] += 1
         for k in big:
             out[k] = jax.device_put(np.asarray(params[k]), dev)
-            self.transfer_stats["direct_transfers"] += 1
-        self.transfer_stats["staged_params"] += len(keys)
         return out
 
     def stage_input(self, x: Any) -> Any:

@@ -73,6 +73,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import json
 import sys
 import time
@@ -91,7 +92,7 @@ from ..frontends import nn
 from ..frontends.extract import extract, extract_decode, extract_prefill
 from ..frontends.optimize import (SolModel, compile_graph, optimize,
                                   provenance_violations)
-from ..runtime import packed
+from ..runtime import packed, telemetry
 from ..runtime.async_queue import AsyncQueue
 from .compile_cache import use_compile_cache
 
@@ -244,6 +245,12 @@ def validate_prompt(cfg: ServeConfig, prompt: Sequence[int]) -> np.ndarray:
 # requests + KV-slot arena
 # ---------------------------------------------------------------------------
 
+def _rids(reqs) -> str:
+    """Request ids as a span attribute: ``"3 5 7"`` (a comma would end the
+    attribute in the profiler's encoding)."""
+    return " ".join(str(r.rid) for r in reqs)
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -256,6 +263,7 @@ class Request:
     slot: Optional[int] = None
     generated: List[int] = dataclasses.field(default_factory=list)
     phase: str = "pending"                   # pending|prefill|decode|done
+    admitted_time: Optional[float] = None
     first_token_time: Optional[float] = None
     finished_time: Optional[float] = None
     last_served_step: int = -1
@@ -461,9 +469,17 @@ class SolServer:
         self._step = 0
         self._t0: Optional[float] = None
         self._t_last: Optional[float] = None
+        # prefill_positions / prefill_real: bucket positions (batch x seq)
+        # against prompt tokens; decode_rows / decode_real: bucket rows
+        # against residents served; d2h_bytes: program outputs brought to
+        # the host; compiles / compile_s: bucket programs built and run for
+        # the first time, and the seconds that took
         self.stats = {"steps": 0, "forwards": 0, "dmas": 0, "tokens": 0,
                       "prefills": 0, "decodes": 0, "admitted": 0,
-                      "evicted": 0, "buckets": {}}
+                      "evicted": 0, "buckets": {},
+                      "prefill_positions": 0, "prefill_real": 0,
+                      "decode_rows": 0, "decode_real": 0, "d2h_bytes": 0,
+                      "compiles": 0, "compile_s": 0.0}
 
     # -- request lifecycle ---------------------------------------------------
 
@@ -486,32 +502,49 @@ class SolServer:
         the rids served this step."""
         if self._t0 is None:
             self._t0 = time.perf_counter()
-        # admission: pending requests claim free KV slots
-        while self._pending and self.arena.free_slots:
-            req = self._pending.popleft()
-            req.slot = self.arena.admit(req.prompt)
-            req.phase = "prefill"
-            self._active.append(req)
-            self.stats["admitted"] += 1
-        if not self._active:
-            return []
+        # admission: pending requests claim free KV slots.  Who is admitted
+        # and who is served are decided up front, so that the step's span
+        # carries its request ids.
+        admit = list(itertools.islice(self._pending, self.arena.free_slots))
         # fairness: least-recently-served first (rid FIFO tiebreak) — every
         # resident request is served at least once per ceil(R/max_batch)
         # steps, so nothing starves
-        batch = sorted(self._active,
+        batch = sorted(self._active + admit,
                        key=lambda r: (r.last_served_step, r.rid)
                        )[: self.cfg.max_batch]
-        # flush staged slot writes; a failed async op re-raises HERE
-        self.queue.synchronize()
-        self._step += 1
-        self.stats["steps"] += 1
-        if self.cfg.decode:
-            results = (self._forward_prefill(
-                           [r for r in batch if r.phase == "prefill"])
-                       + self._forward_decode(
-                           [r for r in batch if r.phase == "decode"]))
-        else:
-            results = self._forward_full(batch)
+        if not batch:
+            return []
+        with telemetry.span("sol.step", step=self._step + 1,
+                            rids=_rids(batch)):
+            if admit:
+                with telemetry.span("sol.admit", rids=_rids(admit)):
+                    for req in admit:
+                        self._pending.popleft()
+                        req.slot = self.arena.admit(req.prompt)
+                        req.admitted_time = time.perf_counter()
+                        req.phase = "prefill"
+                        self._active.append(req)
+                        self.stats["admitted"] += 1
+            # flush staged slot writes; a failed async op re-raises HERE
+            with telemetry.span("sol.arena.sync"):
+                self.queue.synchronize()
+            self._step += 1
+            self.stats["steps"] += 1
+            if self.cfg.decode:
+                results = (self._forward_prefill(
+                               [r for r in batch if r.phase == "prefill"])
+                           + self._forward_decode(
+                               [r for r in batch if r.phase == "decode"]))
+            else:
+                results = self._forward_full(batch)
+            with telemetry.span("sol.sample",
+                                rids=_rids(r for r, _ in results)):
+                self._sample(results)
+        self._t_last = time.perf_counter()
+        return [r.rid for r in batch]
+
+    def _sample(self, results: List[Tuple[Request, np.ndarray]]) -> None:
+        """Sample each served row's token, append it, evict the finished."""
         now = time.perf_counter()
         for req, row in results:
             req.last_logits = row
@@ -537,8 +570,6 @@ class SolServer:
                 self._finished.append(req)
             else:
                 self.arena.append(req.slot, tok)
-        self._t_last = time.perf_counter()
-        return [r.rid for r in batch]
 
     # -- the three forward programs ------------------------------------------
 
@@ -546,20 +577,14 @@ class SolServer:
                       ) -> List[Tuple[Request, np.ndarray]]:
         """Baseline scheduler (``decode=False``): every step re-runs the
         whole resident context through the plain forward graph."""
-        rows_tok = [self.arena.tokens(r.slot) for r in batch]
-        lens = [len(t) for t in rows_tok]
+        lens = [r.length for r in batch]
         bb, sb = self._bucket(len(batch), max(lens))
-        rows = []
-        for t in rows_tok:
-            padded = np.zeros(sb, np.int32)
-            padded[: len(t)] = t
-            rows.append(self.embed[padded])            # (sb, d_model) f32
-        for _ in range(bb - len(batch)):
-            rows.append(np.zeros((sb, self.cfg.d_model), np.float32))
+        with telemetry.span("sol.gather"):
+            rows = self._prompt_rows(batch, bb, sb)
         x = packed.stage_batch(rows, self._device)     # ONE DMA
         self.stats["dmas"] += 1
         self.stats["forwards"] += 1
-        logits = np.asarray(self._model_for(("full", bb, sb))(x))
+        logits, = self._fetch(self._run(("full", bb, sb), x))
         self._bucket_stat(f"{bb}x{sb}")
         return [(r, logits[i, lens[i] - 1].copy())
                 for i, r in enumerate(batch)]
@@ -572,29 +597,30 @@ class SolServer:
         arena's virtual pointers."""
         if not reqs:
             return []
-        rows_tok = [self.arena.tokens(r.slot) for r in reqs]
-        lens = [len(t) for t in rows_tok]
+        lens = [r.length for r in reqs]
         bb, sb = self._bucket(len(reqs), max(lens))
-        rows = []
-        for t in rows_tok:
-            padded = np.zeros(sb, np.int32)
-            padded[: len(t)] = t
-            rows.append(self.embed[padded])
-        for _ in range(bb - len(reqs)):
-            rows.append(np.zeros((sb, self.cfg.d_model), np.float32))
-        x = packed.stage_batch(rows, self._device)     # ONE DMA
-        self.stats["dmas"] += 1
-        self.stats["forwards"] += 1
-        outs = self._model_for(("prefill", bb, sb))(x)
-        logits = np.asarray(outs[0])                   # (bb, sb, vocab)
-        kv = [np.asarray(o) for o in outs[1:]]         # (bb, sb, KV, hd)
-        results = []
-        for i, r in enumerate(reqs):
-            for t in range(len(kv)):
-                self.arena.write_kv_rows(r.slot, t, 0, kv[t][i, : lens[i]])
-            # copy: a bare slice would pin the whole step's logits tensor
-            # in memory for as long as the request record lives
-            results.append((r, logits[i, lens[i] - 1].copy()))
+        real = sum(lens)
+        self.stats["prefill_positions"] += bb * sb
+        self.stats["prefill_real"] += real
+        with telemetry.span("sol.prefill", bucket=f"{bb}x{sb}",
+                            rids=_rids(reqs), real=real,
+                            padded=bb * sb - real):
+            with telemetry.span("sol.gather"):
+                rows = self._prompt_rows(reqs, bb, sb)
+            x = packed.stage_batch(rows, self._device)     # ONE DMA
+            self.stats["dmas"] += 1
+            self.stats["forwards"] += 1
+            # logits (bb, sb, vocab), then (k, v) rows (bb, sb, KV, hd)
+            logits, *kv = self._fetch(self._run(("prefill", bb, sb), x))
+            results = []
+            with telemetry.span("sol.kv_write"):
+                for i, r in enumerate(reqs):
+                    for t in range(len(kv)):
+                        self.arena.write_kv_rows(r.slot, t, 0,
+                                                 kv[t][i, : lens[i]])
+                    # copy: a bare slice would pin the whole step's logits
+                    # tensor in memory for as long as the request lives
+                    results.append((r, logits[i, lens[i] - 1].copy()))
         self._bucket_stat(f"{bb}x{sb}")
         return results
 
@@ -608,30 +634,76 @@ class SolServer:
             return []
         lens = [r.cache_len for r in reqs]
         db, cb = self._bucket(len(reqs), max(lens))
-        x = np.zeros((db, 1, self.cfg.d_model), np.float32)
-        lens_arr = np.zeros((db,), np.int32)
-        caches = [np.zeros((db, cb) + shape, np.float32)
-                  for shape in self._kv_row_shapes]
-        for i, r in enumerate(reqs):
-            x[i, 0] = self.embed[r.generated[-1]]
-            lens_arr[i] = lens[i]
-            for t in range(len(caches)):
-                caches[t][i, : lens[i]] = self.arena.kv_rows(
-                    r.slot, t, lens[i])
-        staged = packed.stage_inputs([x, lens_arr] + caches,
-                                     self._device)    # ONE DMA
-        self.stats["dmas"] += 1
-        self.stats["forwards"] += 1
-        outs = self._model_for(("decode", db, cb))(*staged)
-        logits = np.asarray(outs[0])                   # (db, 1, vocab)
-        results = []
-        for i, r in enumerate(reqs):
-            for t in range(len(caches)):
-                self.arena.write_kv_rows(r.slot, t, lens[i],
-                                         np.asarray(outs[1 + t])[i])
-            results.append((r, logits[i, 0].copy()))
+        self.stats["decode_rows"] += db
+        self.stats["decode_real"] += len(reqs)
+        with telemetry.span("sol.decode", bucket=f"{db}x{cb}",
+                            rids=_rids(reqs), real=len(reqs),
+                            padded=db - len(reqs)):
+            with telemetry.span("sol.gather"):
+                x = np.zeros((db, 1, self.cfg.d_model), np.float32)
+                lens_arr = np.zeros((db,), np.int32)
+                caches = [np.zeros((db, cb) + shape, np.float32)
+                          for shape in self._kv_row_shapes]
+                for i, r in enumerate(reqs):
+                    x[i, 0] = self.embed[r.generated[-1]]
+                    lens_arr[i] = lens[i]
+                    for t in range(len(caches)):
+                        caches[t][i, : lens[i]] = self.arena.kv_rows(
+                            r.slot, t, lens[i])
+            staged = packed.stage_inputs([x, lens_arr] + caches,
+                                         self._device)    # ONE DMA
+            self.stats["dmas"] += 1
+            self.stats["forwards"] += 1
+            # logits (db, 1, vocab), then one (k, v) row per request
+            logits, *kv = self._fetch(self._run(("decode", db, cb), *staged))
+            results = []
+            with telemetry.span("sol.kv_write"):
+                for i, r in enumerate(reqs):
+                    for t in range(len(kv)):
+                        self.arena.write_kv_rows(r.slot, t, lens[i],
+                                                 kv[t][i])
+                    results.append((r, logits[i, 0].copy()))
         self._bucket_stat(f"d{db}x{cb}")
         return results
+
+    def _prompt_rows(self, reqs: List[Request], bb: int, sb: int
+                     ) -> List[np.ndarray]:
+        """Each request's context from its arena slot, embedded and padded
+        to ``sb`` rows, plus zero rows up to the batch bucket ``bb``."""
+        rows = []
+        for r in reqs:
+            padded = np.zeros(sb, np.int32)
+            t = self.arena.tokens(r.slot)
+            padded[: len(t)] = t
+            rows.append(self.embed[padded])            # (sb, d_model) f32
+        for _ in range(bb - len(reqs)):
+            rows.append(np.zeros((sb, self.cfg.d_model), np.float32))
+        return rows
+
+    def _run(self, key: Tuple, *args):
+        """Call a bucket program.  Its first call builds it (extract, elect,
+        lower), compiles or loads it and waits for its outputs, all inside
+        ``sol.compile``."""
+        with telemetry.span("sol.forward"):
+            model = self._models.get(key)
+            if model is not None:
+                return model(*args)
+            program, b, s = key
+            with telemetry.span("sol.compile", program=program,
+                                bucket=f"{b}x{s}") as sp:
+                out = jax.block_until_ready(self._model_for(key)(*args))
+            self.stats["compiles"] += 1
+            self.stats["compile_s"] += sp.t1 - sp.t0
+            return out
+
+    def _fetch(self, outs) -> List[np.ndarray]:
+        """Bring a bucket program's outputs to the host; this waits for the
+        program to finish."""
+        outs = outs if isinstance(outs, (tuple, list)) else (outs,)
+        nbytes = sum(int(o.nbytes) for o in outs)
+        self.stats["d2h_bytes"] += nbytes
+        with telemetry.span("sol.fetch", bytes=nbytes):
+            return [np.asarray(o) for o in outs]
 
     def _bucket_stat(self, key: str) -> None:
         self.stats["buckets"][key] = self.stats["buckets"].get(key, 0) + 1

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .telemetry import span
+
 LATENCY_THRESHOLD_BYTES = 1 << 14     # small transfers go direct
 
 # Process-wide transfer accounting: how many DMAs (packed vs direct) the
@@ -68,10 +70,12 @@ def pack_transfer(arrays: Sequence[np.ndarray],
         layout.append((tuple(a.shape), str(a.dtype), off))
         total = off + a.nbytes
         aligned.append(a)
-    buf = np.zeros(total, np.uint8)
-    for a, (_, _, off) in zip(aligned, layout):
-        buf[off:off + a.nbytes] = a.view(np.uint8).reshape(-1)
-    dev_buf = jax.device_put(buf, device)
+    with span("sol.stage.pack"):
+        buf = np.zeros(total, np.uint8)
+        for a, (_, _, off) in zip(aligned, layout):
+            buf[off:off + a.nbytes] = a.view(np.uint8).reshape(-1)
+    with span("sol.stage.put"):
+        dev_buf = jax.device_put(buf, device)
     return PackedTransfer(dev_buf, layout)
 
 
@@ -87,17 +91,18 @@ def unpack_on_device(pt: PackedTransfer) -> List[jax.Array]:
 def _unpack_jit(layout: Tuple[Tuple[Tuple[int, ...], str, int], ...]):
     def f(buf):
         out = []
-        for shape, dtype, off in layout:
-            item = np.dtype(dtype).itemsize
-            n = int(np.prod(shape)) * item
-            if n == 0:
-                out.append(jnp.zeros(shape, dtype))
-                continue
-            chunk = jax.lax.dynamic_slice(buf, (off,), (n,))
-            # bitcast uint8 → dtype folds the trailing itemsize dim
-            arr = jax.lax.bitcast_convert_type(
-                chunk.reshape(-1, item), jnp.dtype(dtype))
-            out.append(arr.reshape(shape))
+        with jax.named_scope("sol.unpack"):
+            for shape, dtype, off in layout:
+                item = np.dtype(dtype).itemsize
+                n = int(np.prod(shape)) * item
+                if n == 0:
+                    out.append(jnp.zeros(shape, dtype))
+                    continue
+                chunk = jax.lax.dynamic_slice(buf, (off,), (n,))
+                # bitcast uint8 → dtype folds the trailing itemsize dim
+                arr = jax.lax.bitcast_convert_type(
+                    chunk.reshape(-1, item), jnp.dtype(dtype))
+                out.append(arr.reshape(shape))
         return out
     return jax.jit(f)
 
@@ -126,9 +131,11 @@ def stage_inputs(arrays: Sequence[np.ndarray], device=None) -> List[jax.Array]:
     if not arrays:
         raise ValueError("stage_inputs needs at least one array")
     arrays = [np.ascontiguousarray(a) for a in arrays]
-    TRANSFER_STATS["bytes"] += sum(a.nbytes for a in arrays)
+    nbytes = sum(a.nbytes for a in arrays)
+    TRANSFER_STATS["bytes"] += nbytes
     TRANSFER_STATS["packed_dmas"] += 1
-    return unpack_on_device(pack_transfer(arrays, device))
+    with span("sol.stage", bytes=nbytes):
+        return unpack_on_device(pack_transfer(arrays, device))
 
 
 def stage_batch(rows: Sequence[np.ndarray], device=None) -> jax.Array:
@@ -148,9 +155,13 @@ def stage_batch(rows: Sequence[np.ndarray], device=None) -> jax.Array:
         raise ValueError(
             f"stage_batch needs uniform rows, got shapes "
             f"{sorted(shapes)} — pad to a common bucket first")
-    TRANSFER_STATS["bytes"] += sum(r.nbytes for r in rows)
-    if len(rows) == 1:
-        TRANSFER_STATS["direct_dmas"] += 1
-        return jnp.stack([jax.device_put(rows[0], device)])
-    TRANSFER_STATS["packed_dmas"] += 1
-    return jnp.stack(unpack_on_device(pack_transfer(rows, device)))
+    nbytes = sum(r.nbytes for r in rows)
+    TRANSFER_STATS["bytes"] += nbytes
+    with span("sol.stage", bytes=nbytes):
+        if len(rows) == 1:
+            TRANSFER_STATS["direct_dmas"] += 1
+            with span("sol.stage.put"):
+                row = jax.device_put(rows[0], device)
+            return jnp.stack([row])
+        TRANSFER_STATS["packed_dmas"] += 1
+        return jnp.stack(unpack_on_device(pack_transfer(rows, device)))
