@@ -1,10 +1,11 @@
 """Serve batched requests THROUGH the SOL pipeline: continuous batching on
 the elected/tuned graph.
 
-Requests are admitted into an AsyncQueue-backed KV-slot arena, padded to
-the same pow2 buckets the autotune cache keys on (so served shapes hit
-measured timings and pinned Tunable configs), staged host→device with one
-packed DMA per step, and decoded by SolModels whose LINEAR/MATMUL/ATTENTION
+Requests are admitted into a slot arena (token regions on the AsyncQueue,
+KV rows resident on the device), padded to the same pow2 buckets the
+autotune cache keys on (so served shapes hit measured timings and pinned
+Tunable configs), staged host→device with one packed DMA per step, and
+decoded by SolModels whose LINEAR/MATMUL/ATTENTION
 elections all carry measured provenance.  The second leg replays the same
 workload from framework-free deploy artifacts (paper Sec. III-C).
 

@@ -20,9 +20,10 @@ Two serving programs (``ServeConfig.decode=True``, the default):
   resident request against the cached keys/values through the
   ``DECODE_ATTENTION`` op: inputs are the last token's embedding
   ``(B, 1, D)``, the per-request cache lengths ``(B,) int32`` and the
-  gathered cache tensors ``(B, cache_bucket, KV, hd)``; outputs are
-  next-token logits plus the new (k, v) rows the scheduler appends at
-  position ``lens[b]``.  Per decoded token the work is O(cache) instead of
+  cache tensors ``(B, cache_bucket, KV, hd)``, built on the device from
+  the arena; outputs are next-token logits plus the new (k, v) rows the
+  scheduler writes at position ``lens[b]``, on the device.  Per decoded
+  token the work is O(cache) instead of
   the O(T·T) full re-forward — the decode program's cost does not grow
   with how much of the sequence was already generated.
 
@@ -32,13 +33,14 @@ measured baseline (``benchmarks/serving.py`` reports both).
 
 Pieces, and which paper mechanism each reproduces:
 
-* :class:`SlotArena` — per-request slots in an ``AsyncQueue``-backed
-  arena: admission ``malloc_async``s the token region AND one KV region
-  per cached tensor, prompt/token writes land via ``memcpy_async``, cache
-  rows are appended with virtual-pointer arithmetic
-  (``ptr + row·row_bytes``), and eviction is an async free of both
-  regions.  Admission blocks when no slot is free — that interleaving is
-  what lets prefill and decode share the machine.
+* :class:`SlotArena` — per-request slots.  The token region is an
+  ``AsyncQueue`` allocation on the host: admission ``malloc_async``s it,
+  prompt/token writes land via ``memcpy_async`` with virtual-pointer
+  arithmetic, and eviction is an async free.  The KV rows live on the
+  device, one buffer per cached tensor for every slot, written in place
+  from the programs' outputs and gathered there for each decode step.
+  Admission blocks when no slot is free — that interleaving is what lets
+  prefill and decode share the machine.
 * **Bucket padding aligned with the autotune cache** — prefill batches pad
   to ``(batch, seq)`` pow2 buckets; decode batches pad to
   ``(batch, cache_len)`` pow2 buckets.  A power of two is its own cache
@@ -47,8 +49,9 @@ Pieces, and which paper mechanism each reproduces:
   configs exactly, never the roofline fallback.
 * **Packed staging** — each prefill forward's embedded rows go
   host→device as ONE DMA via ``runtime.packed.stage_batch``; each decode
-  forward's mixed inputs (token rows, int32 lengths, KV caches) go as ONE
-  DMA via ``runtime.packed.stage_inputs`` (the VEO-udma gather policy).
+  forward's mixed inputs (token rows, int32 lengths, int32 slot ids) go as
+  ONE DMA via ``runtime.packed.stage_inputs`` (the VEO-udma gather
+  policy).  Only the logits come back.
 * **Continuous batching** — the scheduler serves the least-recently-served
   ``max_batch`` residents each step (starvation-free round-robin), then
   partitions them: freshly admitted requests run the prefill program,
@@ -97,7 +100,6 @@ from ..runtime.async_queue import AsyncQueue
 from .compile_cache import use_compile_cache
 
 TOKEN_BYTES = 4                    # int32 tokens in the slot arena
-KV_BYTES = 4                       # float32 cache rows in the slot arena
 MIN_SEQ_BUCKET = 8                 # smallest padded sequence bucket
 SERVED_KINDS = (OpKind.LINEAR, OpKind.MATMUL, OpKind.ATTENTION,
                 OpKind.DECODE_ATTENTION)
@@ -286,34 +288,80 @@ class Request:
         return self.length - 1
 
 
+@dataclasses.dataclass(frozen=True)
+class KVRows:
+    """Rows ``[0, n)`` of batch entry ``index`` of a bucket program's cache
+    outputs: one ``(batch, rows) + row_shape`` array per cached tensor, in
+    the arena's tensor order, left where the program put them."""
+    outputs: Sequence[Any]
+    index: int
+    n: int
+
+
+def _write_rows(bufs, outs, index, slot, start, n):
+    """Rows ``[0, n)`` of ``outs[t][index]`` into ``bufs[t][slot]`` from row
+    ``start``; the block's rows past ``n`` keep what they held.  Traced per
+    buffer set and program bucket; the rest are dynamic scalars."""
+    new = []
+    for buf, out in zip(bufs, outs):
+        rows = jax.lax.dynamic_index_in_dim(out, index, 0).astype(buf.dtype)
+        at = (slot, start) + (0,) * (buf.ndim - 2)
+        old = jax.lax.dynamic_slice(buf, at, rows.shape)
+        keep = (jnp.arange(rows.shape[1]) < n).reshape(
+            (1, -1) + (1,) * (buf.ndim - 2))
+        new.append(jax.lax.dynamic_update_slice(
+            buf, jnp.where(keep, rows, old), at))
+    return tuple(new)
+
+
+def _gather_rows(bufs, slots, rows):
+    """Rows ``[0, rows)`` of each listed slot, ``(len(slots), rows) +
+    row_shape`` per buffer; a slot id past the last slot reads zeros."""
+    return tuple(jnp.take(buf[:, :rows], slots, axis=0, mode="fill",
+                          fill_value=0) for buf in bufs)
+
+
 class SlotArena:
-    """Per-request slots backed by the async queue's virtual allocator
-    (paper Sec. IV-C).  A slot holds the request's materialized token
-    context (``max_seq`` int32s) and — when ``kv_row_shapes`` is given —
-    one KV region per cached tensor (``max_seq`` float32 rows each, all
-    tensors packed into a single allocation with per-tensor offsets).
-    Admission/append/evict are all enqueued operations, so the arena
-    exercises the exact machinery the runtime bugfixes harden:
-    snapshot-at-enqueue memcopies, error re-raising at ``synchronize``,
-    loud use-after-free."""
+    """Per-request slots (paper Sec. IV-C), with their regions in two places.
+
+    - **Token region, on the host**: ``max_seq`` int32s per slot in the
+      async queue's virtual allocator.  Admission ``malloc_async``s it, the
+      prompt and each decoded token land by ``memcpy_async`` at
+      virtual-pointer offsets, eviction is an async free — the machinery
+      the runtime bugfixes harden: snapshot-at-enqueue memcopies, error
+      re-raising at ``synchronize``, loud use-after-free.  Prefill reads
+      it (``tokens``).
+    - **KV regions, on the device** (when ``kv_row_shapes`` is given): one
+      float32 buffer per cached tensor, ``(n_slots, max_seq) + row_shape``,
+      on ``device`` (a device or a sharding: the server's staging target),
+      zero-filled at construction and resident for the arena's life.  Slot
+      ``s`` owns row ``s`` of every buffer.  ``write_kv_rows`` updates it
+      in place from the programs' outputs and ``gather`` builds a decode
+      bucket's cache inputs from it, so cache rows never cross to the
+      host."""
 
     def __init__(self, queue: AsyncQueue, n_slots: int, max_seq: int,
-                 kv_row_shapes: Optional[Sequence[Tuple[int, ...]]] = None):
+                 kv_row_shapes: Optional[Sequence[Tuple[int, ...]]] = None,
+                 device=None):
         self.queue = queue
+        self.n_slots = n_slots
         self.max_seq = max_seq
         self._free = list(range(n_slots - 1, -1, -1))
         self._ptr: Dict[int, Any] = {}
         self._len: Dict[int, int] = {}
         self.kv_row_shapes = [tuple(s) for s in (kv_row_shapes or [])]
-        self._row_bytes = [int(np.prod(s)) * KV_BYTES
-                           for s in self.kv_row_shapes]
-        self._kv_offs: List[int] = []
-        total = 0
-        for rb in self._row_bytes:
-            self._kv_offs.append(total)
-            total += max_seq * rb
-        self._kv_total = total
-        self._kv_ptr: Dict[int, Any] = {}
+        zeros = [jnp.zeros((n_slots, max_seq) + s, jnp.float32,
+                           device=device) for s in self.kv_row_shapes]
+        # committed where they are, so that the first write is compiled for
+        # the placement every later write sees
+        self._kv = [jax.device_put(z, z.sharding) for z in zeros]
+        # a sharded target (mesh mode) pins the outputs' layout, so the
+        # donated buffers are updated in place
+        out = device if isinstance(device, jax.sharding.Sharding) else None
+        self._write = jax.jit(_write_rows, donate_argnums=0,
+                              out_shardings=out)
+        self._gather = jax.jit(_gather_rows, static_argnums=2,
+                               out_shardings=out)
 
     @property
     def free_slots(self) -> int:
@@ -324,9 +372,9 @@ class SlotArena:
         return len(self._ptr)
 
     def admit(self, tokens: np.ndarray) -> Optional[int]:
-        """Allocate a slot (token region + KV regions) and stage the prompt
-        into it; None when full (the request waits in the pending queue —
-        admission control)."""
+        """Allocate a slot's token region and stage the prompt into it;
+        None when full (the request waits in the pending queue — admission
+        control).  The slot's KV rows are already there."""
         if not self._free:
             return None
         tokens = np.ascontiguousarray(tokens, np.int32)
@@ -338,8 +386,6 @@ class SlotArena:
         self.queue.memcpy_async(ptr, tokens)
         self._ptr[slot] = ptr
         self._len[slot] = len(tokens)
-        if self._kv_total:
-            self._kv_ptr[slot] = self.queue.malloc_async(self._kv_total)
         return slot
 
     def append(self, slot: int, token: int) -> None:
@@ -359,36 +405,52 @@ class SlotArena:
         n = self._len[slot]
         return buf[:n * TOKEN_BYTES].view(np.int32).copy()
 
-    def write_kv_rows(self, slot: int, tensor: int, start_row: int,
-                      rows: np.ndarray) -> None:
-        """Stage cache rows ``[start_row, start_row + n)`` of one cached
-        tensor — prefill seeds ``[0, L)`` in one write, decode appends one
-        row at ``lens[b]`` — all virtual-pointer arithmetic into the slot's
-        single KV allocation."""
-        rows = np.ascontiguousarray(rows, np.float32)
-        n = rows.shape[0]
-        if start_row + n > self.max_seq:
-            raise ValueError(f"KV write [{start_row}, {start_row + n}) "
-                             f"overflows the {self.max_seq}-row slot")
-        rb = self._row_bytes[tensor]
-        self.queue.memcpy_async(
-            self._kv_ptr[slot] + self._kv_offs[tensor] + start_row * rb,
-            rows)
+    def write_kv_rows(self, slot: int, tensor: Optional[int], start_row: int,
+                      rows) -> None:
+        """Write cache rows ``[start_row, start_row + n)`` of a slot, in
+        place on the device: prefill seeds ``[0, L)``, decode appends one
+        row at ``lens[b]``.  With ``tensor=None`` one dispatch writes every
+        cached tensor from ``rows``, a :class:`KVRows`; an int ``tensor``
+        writes that tensor alone from ``rows`` shaped ``(n,) + row_shape``."""
+        if tensor is None:
+            outs, index, n = tuple(rows.outputs), rows.index, rows.n
+            which = range(len(self._kv))
+        else:
+            outs, index, n = (jnp.asarray(rows, jnp.float32)[None],), 0, \
+                len(rows)
+            which = [tensor]
+        end = start_row + outs[0].shape[1]
+        if end > self.max_seq:
+            raise ValueError(f"KV write [{start_row}, {end}) overflows the "
+                             f"{self.max_seq}-row slot")
+        new = self._write(tuple(self._kv[t] for t in which), outs,
+                          int(index), int(slot), int(start_row), int(n))
+        for t, buf in zip(which, new):
+            self._kv[t] = buf
+
+    def gather(self, slots: jax.Array, rows: int) -> List[jax.Array]:
+        """A decode bucket's cache inputs, built on the device in one
+        dispatch: rows ``[0, rows)`` of each slot in ``slots``, one
+        ``(len(slots), rows) + row_shape`` array per cached tensor.  A
+        batch-padding entry holds ``n_slots`` and reads zeros.
+
+        Rows at or past a request's length hold whatever an earlier tenant
+        of the slot left there.  They weigh exactly 0: DECODE_ATTENTION
+        sets every logit at or past ``lens[b]`` to -1e30 before its softmax
+        (the Pallas kernel and ``ref.py`` alike), and the buffers only ever
+        hold zeros and program outputs, all finite, so 0 x row is 0."""
+        return list(self._gather(tuple(self._kv), slots, rows))
 
     def kv_rows(self, slot: int, tensor: int, n_rows: int) -> np.ndarray:
         """The first ``n_rows`` cache rows of one cached tensor, shaped
-        ``(n_rows,) + row_shape``.  Callers must ``synchronize`` first."""
-        buf = self.queue.allocator.resolve(self._kv_ptr[slot])
-        off = self._kv_offs[tensor]
-        rb = self._row_bytes[tensor]
-        return (buf[off: off + n_rows * rb].view(np.float32)
-                .reshape((n_rows,) + self.kv_row_shapes[tensor]).copy())
+        ``(n_rows,) + row_shape``: a device→host read, for tests and
+        debugging."""
+        return np.asarray(self._kv[tensor][slot, :n_rows])
 
     def evict(self, slot: int) -> None:
+        """Free the slot's token region; its KV rows stay, to be
+        overwritten by the next tenant."""
         self.queue.free_async(self._ptr.pop(slot))
-        kv = self._kv_ptr.pop(slot, None)
-        if kv is not None:
-            self.queue.free_async(kv)
         del self._len[slot]
         self._free.append(slot)
 
@@ -456,7 +518,8 @@ class SolServer:
         else:
             self._kv_row_shapes = []
         self.arena = SlotArena(self.queue, self.cfg.slots, self.cfg.max_seq,
-                               kv_row_shapes=self._kv_row_shapes)
+                               kv_row_shapes=self._kv_row_shapes,
+                               device=self._device)
         if deployed is not None:
             from ..frontends import deploy as D
             for key, art in deployed.items():
@@ -472,13 +535,17 @@ class SolServer:
         # prefill_positions / prefill_real: bucket positions (batch x seq)
         # against prompt tokens; decode_rows / decode_real: bucket rows
         # against residents served; d2h_bytes: program outputs brought to
-        # the host; compiles / compile_s: bucket programs built and run for
-        # the first time, and the seconds that took
+        # the host (the logits); kv_rows_written: cache positions written on
+        # the device (each in every cached tensor); kv_host_bytes: cache
+        # bytes that crossed host<->device (0 unless the programs hand back
+        # host arrays); compiles / compile_s: bucket programs built and run
+        # for the first time, and the seconds that took
         self.stats = {"steps": 0, "forwards": 0, "dmas": 0, "tokens": 0,
                       "prefills": 0, "decodes": 0, "admitted": 0,
                       "evicted": 0, "buckets": {},
                       "prefill_positions": 0, "prefill_real": 0,
                       "decode_rows": 0, "decode_real": 0, "d2h_bytes": 0,
+                      "kv_rows_written": 0, "kv_host_bytes": 0,
                       "compiles": 0, "compile_s": 0.0}
 
     # -- request lifecycle ---------------------------------------------------
@@ -584,7 +651,7 @@ class SolServer:
         x = packed.stage_batch(rows, self._device)     # ONE DMA
         self.stats["dmas"] += 1
         self.stats["forwards"] += 1
-        logits, = self._fetch(self._run(("full", bb, sb), x))
+        logits = self._fetch(self._run(("full", bb, sb), x))
         self._bucket_stat(f"{bb}x{sb}")
         return [(r, logits[i, lens[i] - 1].copy())
                 for i, r in enumerate(batch)]
@@ -593,8 +660,8 @@ class SolServer:
                          ) -> List[Tuple[Request, np.ndarray]]:
         """Prompt forward through the prefill program: produces the first
         token's logits AND the (k, v) rows that seed each request's KV
-        slot — rows ``[0, L)`` of every cached tensor, written through the
-        arena's virtual pointers."""
+        slot — rows ``[0, L)`` of every cached tensor, written on the
+        device from the program's outputs."""
         if not reqs:
             return []
         lens = [r.length for r in reqs]
@@ -611,25 +678,22 @@ class SolServer:
             self.stats["dmas"] += 1
             self.stats["forwards"] += 1
             # logits (bb, sb, vocab), then (k, v) rows (bb, sb, KV, hd)
-            logits, *kv = self._fetch(self._run(("prefill", bb, sb), x))
-            results = []
-            with telemetry.span("sol.kv_write"):
-                for i, r in enumerate(reqs):
-                    for t in range(len(kv)):
-                        self.arena.write_kv_rows(r.slot, t, 0,
-                                                 kv[t][i, : lens[i]])
-                    # copy: a bare slice would pin the whole step's logits
-                    # tensor in memory for as long as the request lives
-                    results.append((r, logits[i, lens[i] - 1].copy()))
+            logits, *kv = self._run(("prefill", bb, sb), x)
+            logits = self._fetch(logits)
+            self._write_kv(reqs, kv, [0] * len(reqs), lens)
+            # copy: a bare slice would pin the whole step's logits tensor in
+            # memory for as long as the request lives
+            results = [(r, logits[i, lens[i] - 1].copy())
+                       for i, r in enumerate(reqs)]
         self._bucket_stat(f"{bb}x{sb}")
         return results
 
     def _forward_decode(self, reqs: List[Request]
                         ) -> List[Tuple[Request, np.ndarray]]:
-        """One token per resident request through the decode program:
-        gather each request's cache rows from its arena slot, pad to the
-        (batch, cache) bucket, stage everything as ONE packed DMA, and
-        append the returned (k, v) rows at position ``lens[b]``."""
+        """One token per resident request through the decode program: stage
+        the token rows, cache lengths and slot ids as ONE packed DMA, build
+        the (batch, cache) bucket's caches from the arena's device buffers,
+        and write the returned (k, v) rows at position ``lens[b]`` there."""
         if not reqs:
             return []
         lens = [r.cache_len for r in reqs]
@@ -639,32 +703,46 @@ class SolServer:
         with telemetry.span("sol.decode", bucket=f"{db}x{cb}",
                             rids=_rids(reqs), real=len(reqs),
                             padded=db - len(reqs)):
-            with telemetry.span("sol.gather"):
-                x = np.zeros((db, 1, self.cfg.d_model), np.float32)
-                lens_arr = np.zeros((db,), np.int32)
-                caches = [np.zeros((db, cb) + shape, np.float32)
-                          for shape in self._kv_row_shapes]
-                for i, r in enumerate(reqs):
-                    x[i, 0] = self.embed[r.generated[-1]]
-                    lens_arr[i] = lens[i]
-                    for t in range(len(caches)):
-                        caches[t][i, : lens[i]] = self.arena.kv_rows(
-                            r.slot, t, lens[i])
-            staged = packed.stage_inputs([x, lens_arr] + caches,
-                                         self._device)    # ONE DMA
+            x = np.zeros((db, 1, self.cfg.d_model), np.float32)
+            lens_arr = np.zeros((db,), np.int32)
+            # a padding entry's slot id is out of range: it reads zeros
+            slots = np.full((db,), self.arena.n_slots, np.int32)
+            for i, r in enumerate(reqs):
+                x[i, 0] = self.embed[r.generated[-1]]
+                lens_arr[i] = lens[i]
+                slots[i] = r.slot
+            x, lens_arr, slots = packed.stage_inputs(
+                [x, lens_arr, slots], self._device)          # ONE DMA
             self.stats["dmas"] += 1
             self.stats["forwards"] += 1
+            with telemetry.span("sol.gather"):
+                caches = self.arena.gather(slots, cb)
             # logits (db, 1, vocab), then one (k, v) row per request
-            logits, *kv = self._fetch(self._run(("decode", db, cb), *staged))
-            results = []
-            with telemetry.span("sol.kv_write"):
-                for i, r in enumerate(reqs):
-                    for t in range(len(kv)):
-                        self.arena.write_kv_rows(r.slot, t, lens[i],
-                                                 kv[t][i])
-                    results.append((r, logits[i, 0].copy()))
+            logits, *kv = self._run(("decode", db, cb), x, lens_arr, *caches)
+            logits = self._fetch(logits)
+            self._write_kv(reqs, kv, lens, [1] * len(reqs))
+            results = [(r, logits[i, 0].copy()) for i, r in enumerate(reqs)]
         self._bucket_stat(f"d{db}x{cb}")
         return results
+
+    def _write_kv(self, reqs: List[Request], kv: Sequence[Any],
+                  starts: List[int], counts: List[int]) -> None:
+        """Write each request's new cache rows into its slot, one arena
+        call per request covering every cached tensor: rows ``[0,
+        counts[i])`` of batch entry ``i`` go to rows ``starts[i]`` on."""
+        host = sum(int(o.nbytes) for o in kv if not isinstance(o, jax.Array))
+        if host or self.mesh is not None:
+            # host arrays (transparent offloading) came down with the
+            # outputs and go back once; on a mesh the programs lay their
+            # cache outputs out per shard, and the arena's buffers are
+            # replicated
+            kv = jax.device_put(kv, self._device)
+        self.stats["kv_host_bytes"] += 2 * host
+        with telemetry.span("sol.kv_write"):
+            for i, r in enumerate(reqs):
+                self.arena.write_kv_rows(r.slot, None, starts[i],
+                                         KVRows(kv, i, counts[i]))
+                self.stats["kv_rows_written"] += counts[i]
 
     def _prompt_rows(self, reqs: List[Request], bb: int, sb: int
                      ) -> List[np.ndarray]:
@@ -696,14 +774,13 @@ class SolServer:
             self.stats["compile_s"] += sp.t1 - sp.t0
             return out
 
-    def _fetch(self, outs) -> List[np.ndarray]:
-        """Bring a bucket program's outputs to the host; this waits for the
-        program to finish."""
-        outs = outs if isinstance(outs, (tuple, list)) else (outs,)
-        nbytes = sum(int(o.nbytes) for o in outs)
+    def _fetch(self, logits) -> np.ndarray:
+        """Bring a bucket program's logits to the host; this waits for the
+        program to finish.  Its cache outputs stay on the device."""
+        nbytes = int(logits.nbytes)
         self.stats["d2h_bytes"] += nbytes
         with telemetry.span("sol.fetch", bytes=nbytes):
-            return [np.asarray(o) for o in outs]
+            return np.asarray(logits)
 
     def _bucket_stat(self, key: str) -> None:
         self.stats["buckets"][key] = self.stats["buckets"].get(key, 0) + 1

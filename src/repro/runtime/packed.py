@@ -124,10 +124,9 @@ def stage_inputs(arrays: Sequence[np.ndarray], device=None) -> List[jax.Array]:
 
     The serving decode step feeds one forward several arrays of different
     shapes and dtypes — token rows (f32), per-request cache lengths (int32)
-    and the gathered KV caches (f32).  They are consumed together by a
-    single dispatch, so like :func:`stage_batch` they are a bandwidth
-    object regardless of size: always one packed segment, resliced on
-    device, never N direct puts."""
+    and slot ids (int32).  They are consumed together by one step, so like
+    :func:`stage_batch` they are a bandwidth object regardless of size:
+    always one packed segment, resliced on device, never N direct puts."""
     if not arrays:
         raise ValueError("stage_inputs needs at least one array")
     arrays = [np.ascontiguousarray(a) for a in arrays]
