@@ -50,11 +50,10 @@ def reseed(cell, seed: int) -> None:
     compiled programs.  The bucket programs' staged copies of the old
     weights are dropped first, so that two sets never share the device."""
     from harness import model as model_mod
-    from harness import weights
     for m in cell.server._models.values():
         m._ctx_params = None
-    model_mod.load_seeded(cell.model, cell.lm, seed)
-    cell.server.embed = weights.embedding(cell.lm, seed)
+    model_mod.load_seeded(cell.model, cell.arch.weights(cell.lm), seed)
+    cell.server.embed = cell.embedding(seed)
 
 
 def main(argv=None) -> int:
@@ -65,7 +64,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     args = ap.parse_args(argv)
     import jax
-    from harness import check, weights
+    from harness import check
     from harness.cell import Cell, log
     from harness.spans import Recorder
     from harness.spec import Spec
@@ -102,10 +101,9 @@ def main(argv=None) -> int:
                 undo()
             rec.uninstall()
         sample = check.sample(done, int(cell.mix["check"]["requests"]), seed)
-        params = weights.make_params(cell.lm, seed)
-        got = check.compare(ref, cell.lm, params,
-                            weights.embedding(cell.lm, seed), sample,
-                            control=not fault)
+        params = cell.params(seed)
+        got = check.compare(ref, cell.lm, params, cell.embedding(seed),
+                            sample, control=not fault)
         del params
         got["passed"] = check.passed(check.verdict(got, w.compiles, limits))
         if not fault:

@@ -1,9 +1,10 @@
 """One cell: set-up, the traffic, the measured window, the check.
 
-``Cell.setup`` builds the framework model and the server, draws the weights
-from the seed, loads (or on the first run measures and saves) the autotune
-cache, and runs every bucket program the mix can open once through the
-server itself.  ``Cell.serve`` drives the traffic: a lead-in, then the
+``Cell.setup`` builds the framework model that the configuration's model
+module (``bench/models/<lm.model>.py``) describes and the server, draws
+the weights from the seed, loads (or on the first run measures and saves)
+the autotune cache, and runs every bucket program the mix can open once
+through the server itself.  ``Cell.serve`` drives the traffic: a lead-in, then the
 window of ``seconds`` in which nothing compiles.  Everything a request saw
 is kept per request on the host clock.
 """
@@ -84,6 +85,7 @@ class Cell:
         self.config = spec.config(self.cell["config"])
         self.mix = spec.traffic(self.cell["traffic"])
         self.lm = lm_widths(self.config)
+        self.arch = spec.model(self.lm["model"])
         self.backend = backend
         self.server = None
         self.model = None
@@ -103,11 +105,17 @@ class Cell:
             f"{self.name}__*.json"))
 
     def _build(self, seed: int) -> None:
-        self.model = model_mod.build(self.lm)
-        model_mod.load_seeded(self.model, self.lm, seed)
-        embed = weights.embedding(self.lm, seed)
-        self.server = model_mod.server(self.lm, self.mix["server"],
-                                       self.model, embed, self.backend)
+        self.model = model_mod.build_seeded(self.arch, self.lm, seed)
+        self.server = model_mod.server(self.arch, self.lm, self.mix["server"],
+                                       self.model, self.embedding(seed),
+                                       self.backend)
+
+    def params(self, seed: int) -> Dict:
+        """The seeded weights, drawn anew (for the reference)."""
+        return weights.make_params(self.arch.weights(self.lm), seed)
+
+    def embedding(self, seed: int):
+        return model_mod.embedding(self.arch, self.lm, seed)
 
     def tune(self, seed: int, device_kind: str) -> None:
         """Measure and save the autotune cache, and nothing else.  A
@@ -221,9 +229,11 @@ class Cell:
         snap: Dict[str, float] = {}
 
         def counters():
-            return {"h2d_bytes": packed.TRANSFER_STATS["bytes"],
-                    "out_bytes": rec.out_bytes,
-                    "compiles": _compiles["n"]}
+            out = {"h2d_bytes": packed.TRANSFER_STATS["bytes"],
+                   "compiles": _compiles["n"]}
+            if "d2h_bytes" in srv.stats:
+                out["d2h_bytes"] = srv.stats["d2h_bytes"]
+            return out
 
         def submit(r: traffic_mod.Request, due: float, now: float):
             state["late"] = max(state["late"], now - due)

@@ -86,7 +86,9 @@ def compare(reference, lm: Dict, params, embed: np.ndarray, records: Sequence,
             control: bool = False) -> Dict[str, float]:
     """Run the reference (and with ``control`` the bfloat16 control) over
     the sampled ``records`` (each with the server's request ``srv`` and the
-    logits row of every served token) and return the readings:
+    logits row of every served token) and return the readings.  The
+    reference is given each sequence's embedded rows and, as ``ids``, its
+    token ids, for a model whose embedding lives in its graph:
 
     - ``logit_rel_mse``: over every served position, the mean squared
       difference of the served logits from the reference's, relative to
@@ -118,12 +120,13 @@ def compare(reference, lm: Dict, params, embed: np.ndarray, records: Sequence,
         mismatches += int((served.argmax(-1) != np.asarray(r.generated))
                           .sum())
         got = jnp.asarray(_padded(served))
-        ref = reference.logits(params, lm, rows)
+        ref = reference.logits(params, lm, rows, ids=seq)
         gap, rel = served_fn(ref, got, idx, tok)
         acc["gap"].append(np.asarray(gap)[:n])
         acc["rel"].append(np.asarray(rel)[:n])
         if control:
-            low = reference.logits(params, lm, rows, dtype=jnp.bfloat16)
+            low = reference.logits(params, lm, rows, dtype=jnp.bfloat16,
+                                   ids=seq)
             cgap, crel = control_fn(ref, low, idx)
             acc["cgap"].append(np.asarray(cgap)[:n])
             acc["crel"].append(np.asarray(crel)[:n])

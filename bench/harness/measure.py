@@ -6,8 +6,6 @@ from typing import Dict, List
 
 import numpy as np
 
-from . import costs
-
 
 def tokens_in(w) -> List[float]:
     return [t for x in w.records for t in x.tokens if w.t0 <= t < w.t1]
@@ -58,15 +56,3 @@ def end_to_end(w, setup_s: float) -> Dict[str, float]:
             "ttft_p90_ms": 1e3 * pct(ttfts_s(w), 90),
             "itl_p95_ms": 1e3 * pct(itls_s(w), 95),
             "setup_s": setup_s}
-
-
-def served_work(w, lm: Dict) -> float:
-    """FLOPs the model requires for the tokens served in the window."""
-    total = 0.0
-    for x in w.records:
-        plen = len(x.req.prompt)
-        for j, t in enumerate(x.tokens):
-            if w.t0 <= t < w.t1:
-                total += (costs.prefill_flops(lm, plen) if j == 0
-                          else costs.decode_flops(lm, plen + j - 1))
-    return total

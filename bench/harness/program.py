@@ -42,6 +42,8 @@ CATEGORY = {
     "sol.decode": "scheduler",
     "sol.forward": "dispatch", "sol.compile": "dispatch",
 }
+# the spans of a bucket forward -> its phase
+FORWARDS = {"sol.prefill": "prefill", "sol.decode": "decode"}
 PARTS = ("staging", "fetch", "scheduler", "dispatch", "other", "outside")
 
 _memo: Dict[str, object] = {"run": None, "split": None}
@@ -73,6 +75,27 @@ def window_spans(run) -> Optional[List[Tuple]]:
         return None
     w = run.window
     return [s for s in step_spans(spans) if w.t0 <= s[1] < w.t1]
+
+
+def window_forwards(run) -> Optional[List[Tuple[str, int, int]]]:
+    """``(phase, batch, seq)`` of every bucket forward of the traced
+    window: the program's ``sol.prefill`` and ``sol.decode`` spans (their
+    ``bucket``, ``"<batch>x<seq>"``) that start in it, put on the trace's
+    clock.  None without the program's spans or a clock offset."""
+    spans = program_spans()
+    if spans is None or not run.records:
+        return None
+    trace_steps = [s for s in run.records["spans"] if s[0] == "bench.step"]
+    clock = clock_offset(run.named("bench.step"), trace_steps)
+    if clock is None:
+        return None
+    w0, w1 = (x * 1e-9 - clock[0] for x in window_of(run.records))
+    out = []
+    for name, t0, _, _, attrs in step_spans(spans):
+        if name in FORWARDS and w0 <= t0 < w1:
+            batch, seq = attrs["bucket"].split("x")
+            out.append((FORWARDS[name], int(batch), int(seq)))
+    return out
 
 
 def clock_offset(host_steps: List[Tuple], trace_steps: List

@@ -26,6 +26,11 @@ class RunData:
     def lm(self) -> Dict:
         return self.cell.lm
 
+    @property
+    def arch(self):
+        """The configuration's model module (``bench/models/<name>.py``)."""
+        return self.cell.arch
+
     def named(self, name: str) -> List[Tuple]:
         return [s for s in self.spans if s[0] == name]
 

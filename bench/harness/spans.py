@@ -29,7 +29,6 @@ class Recorder:
         self.sync = sync
         self.spans: List[Span] = []
         self.on = False                 # record only inside the window
-        self.out_bytes = 0              # bytes of bucket-program outputs
         self._undo: List[Callable[[], None]] = []
 
     @contextlib.contextmanager
@@ -76,9 +75,6 @@ class Recorder:
                 out = forward(model, *xs)
                 if self.sync:
                     jax.block_until_ready(out)
-            if self.on:
-                leaves = out if isinstance(out, (tuple, list)) else (out,)
-                self.out_bytes += sum(int(o.nbytes) for o in leaves)
             return out
         return call
 

@@ -8,6 +8,10 @@ harness finds every piece by that name and needs no edit for a new one:
 - ``bench/limits/<cell>.json``: the limits of the comparison that decides
   ``correct``;
 - ``bench/metrics/<metric>.py``: one reader per per-layer metric;
+- ``bench/models/<name>.py``: the architecture a configuration names
+  under ``lm.model``: ``build(lm)``, its weight table ``weights(lm)``,
+  ``prefill_flops``, ``decode_flops`` and ``kernel_work`` (and optionally
+  ``embedding`` and ``server``; see ``harness.model``);
 - ``bench/references/<name>.py``: the plain reference a configuration
   names under ``lm.reference``.
 """
@@ -81,6 +85,9 @@ class Spec:
     def reference(self, name: str):
         return load_module(self.bench / "references" / f"{name}.py")
 
+    def model(self, name: str):
+        return load_module(self.bench / "models" / f"{name}.py")
+
 
 def load_module(path: Path):
     """Import a file whose name may hold dots (``sched.queue_wait_ms.py``)."""
@@ -95,9 +102,10 @@ def load_module(path: Path):
 
 
 def lm_widths(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The served LM's widths, from the configuration's ``lm`` block."""
+    """The served LM's widths, from the configuration's ``lm`` block;
+    ``head_dim`` is ``d_model // n_heads`` where the block gives none."""
     lm = dict(config["lm"])
-    lm["head_dim"] = lm["d_model"] // lm["n_heads"]
+    lm.setdefault("head_dim", lm["d_model"] // lm["n_heads"])
     return lm
 
 

@@ -1,16 +1,18 @@
 """The served model's weights, made from ``--seed``.
 
-Every weight of the model is drawn on the device in one jitted call, in
-float32 (the type it is served in), under the state-dict names of the
-framework model that ``model.build`` makes.  The plain reference draws the
-same weights with the same call after the program's state is freed, so it
-takes nothing that the program made.  The token embedding is a host table,
-as the server keeps it, drawn with numpy.
+A model module (``bench/models/<name>.py``) gives its weights as a table
+of ``Group``s: name, shape, kind and scale of every weight.  Every weight
+is drawn on the device in one jitted call over that table, in float32 (the
+type it is served in), under the state-dict names of the framework model
+the module builds.  The plain reference draws the same weights with the
+same call after the program's state is freed, so it takes nothing that the
+program made.  The token embedding is a host table, as the server keeps it,
+drawn with numpy.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -24,83 +26,78 @@ GAIN_SD = 0.1
 NORM_BIAS_SD = 0.1
 BIAS_SD = 0.02
 
-
-def _layer_table(lm: Dict):
-    """``(suffix, shape, kind, scale)`` of every weight of one block, in the
-    framework model's state-dict naming: block ``i`` is ``i.0`` (attention
-    residual: ``.0`` LayerNorm, ``.1`` attention) and ``i.1`` (MLP residual:
-    ``.0`` LayerNorm, ``.1`` and ``.3`` Linear)."""
-    d, f = lm["d_model"], lm["d_ff"]
-    hd = d // lm["n_heads"]
-    q, kv = lm["n_heads"] * hd, lm["n_kv_heads"] * hd
-    return [("0.0.weight", (d,), "gain", GAIN_SD),
-            ("0.0.bias", (d,), "normal", NORM_BIAS_SD),
-            ("0.1.wq", (d, q), "normal", d ** -0.5),
-            ("0.1.wk", (d, kv), "normal", d ** -0.5),
-            ("0.1.wv", (d, kv), "normal", d ** -0.5),
-            ("0.1.wo", (q, d), "normal", q ** -0.5),
-            ("1.0.weight", (d,), "gain", GAIN_SD),
-            ("1.0.bias", (d,), "normal", NORM_BIAS_SD),
-            ("1.1.weight", (f, d), "normal", d ** -0.5),
-            ("1.1.bias", (f,), "normal", BIAS_SD),
-            ("1.3.weight", (d, f), "normal", f ** -0.5),
-            ("1.3.bias", (d,), "normal", BIAS_SD)]
+# (name, shape, kind, scale); kind "gain" is drawn around 1, "normal"
+# around 0; "{i}" in a name stands for the layer's index
+Entry = Tuple[str, Tuple[int, ...], str, float]
 
 
-def _head_table(lm: Dict):
-    d, v = lm["d_model"], lm["vocab"]
-    return [("weight", (v, d), "normal", d ** -0.5),
-            ("bias", (v,), "normal", BIAS_SD)]
+class Group(NamedTuple):
+    """Weights drawn together.  Entry ``j`` is one draw under
+    ``fold_in(key, salt + j)``: with ``layers``, a stack of
+    ``(len(layers),) + shape`` split into one weight per layer index;
+    without, one weight.  Layers of different kinds are groups of their
+    own, each with the indices of its kind (``layer_kinds``) and a salt
+    that no other group's entries reach."""
+    salt: int
+    layers: Optional[Tuple[int, ...]]
+    entries: Tuple[Entry, ...]
 
 
-def shapes(lm: Dict) -> Dict[str, Tuple[int, ...]]:
-    """``name -> shape`` of every weight; the head is block ``n_layers``."""
-    out = {f"{i}.{sfx}": shape for i in range(lm["n_layers"])
-           for sfx, shape, _, _ in _layer_table(lm)}
-    out.update({f"{lm['n_layers']}.{sfx}": shape
-                for sfx, shape, _, _ in _head_table(lm)})
-    return out
+def layer_kinds(n_layers: int, period: Sequence[str],
+                leading: Sequence[str] = ()) -> Dict[str, Tuple[int, ...]]:
+    """``kind -> layer indices``: the ``leading`` layers first, one of each
+    kind named, then ``period`` repeated over the rest."""
+    kinds = list(leading) + [period[k % len(period)]
+                             for k in range(n_layers - len(leading))]
+    return {k: tuple(i for i, x in enumerate(kinds) if x == k)
+            for k in dict.fromkeys(kinds)}
 
 
-def make_params(lm: Dict, seed: int, dtype=None) -> Dict:
+def shapes(table: Sequence[Group]) -> Dict[str, Tuple[int, ...]]:
+    """``name -> shape`` of every weight."""
+    return {name.format(i=i): shape for g in table
+            for name, shape, _, _ in g.entries
+            for i in (g.layers if g.layers is not None else (None,))}
+
+
+def make_params(table: Sequence[Group], seed: int, dtype=None) -> Dict:
     """Every weight, on the default device, from one jitted call."""
     import jax.numpy as jnp
     words = np.random.SeedSequence(seed_words(seed, 1)).generate_state(2)
-    key = tuple(sorted((k, v) for k, v in lm.items()
-                       if isinstance(v, int)))
-    return _draw(key, jnp.dtype(dtype or "float32"))(
+    return _draw(tuple(table), jnp.dtype(dtype or "float32"))(
         jnp.asarray(words, jnp.uint32))
 
 
 @functools.lru_cache(maxsize=8)
-def _draw(lm_key, dtype):
+def _draw(table: Tuple[Group, ...], dtype):
     import jax
     import jax.numpy as jnp
-    lm = dict(lm_key)
-    n = lm["n_layers"]
 
     def normal(key, shape, kind, scale):
         x = jax.random.normal(key, shape, jnp.float32) * scale
         return (x + 1.0 if kind == "gain" else x).astype(dtype)
 
     def draw(words):
-        # one draw per kind of weight for all blocks at once, then split:
-        # a dozen random ops to compile, not one per weight
+        # one draw per entry for all its layers at once, then split: a
+        # dozen random ops to compile, not one per weight
         key = jax.random.fold_in(jax.random.key(words[0]), words[1])
         out = {}
-        for j, (sfx, shape, kind, scale) in enumerate(_layer_table(lm)):
-            stack = normal(jax.random.fold_in(key, j), (n,) + shape, kind,
-                           scale)
-            out.update({f"{i}.{sfx}": stack[i] for i in range(n)})
-        for j, (sfx, shape, kind, scale) in enumerate(_head_table(lm)):
-            out[f"{n}.{sfx}"] = normal(jax.random.fold_in(key, 100 + j),
-                                       shape, kind, scale)
+        for g in table:
+            for j, (name, shape, kind, scale) in enumerate(g.entries):
+                k = jax.random.fold_in(key, g.salt + j)
+                if g.layers is None:
+                    out[name] = normal(k, shape, kind, scale)
+                    continue
+                stack = normal(k, (len(g.layers),) + shape, kind, scale)
+                out.update({name.format(i=i): stack[r]
+                            for r, i in enumerate(g.layers)})
         return out
     return jax.jit(draw)
 
 
 def embedding(lm: Dict, seed: int) -> np.ndarray:
     """The host token-embedding table, ``(vocab, d_model)`` float32 with
-    unit-variance entries."""
+    unit-variance entries: the harness's own, for a model module that
+    gives none."""
     rng = np.random.default_rng(seed_words(seed, 2))
     return rng.standard_normal((lm["vocab"], lm["d_model"]), np.float32)

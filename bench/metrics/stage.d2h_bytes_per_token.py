@@ -1,9 +1,11 @@
-"""Staging: bytes of the bucket programs' outputs in the window (logits
-and new KV rows, which the server brings to the host) per output token."""
+"""Staging: bytes the program brought from the device to the host in the
+window (the window's difference of ``SolServer.stats["d2h_bytes"]``: the
+logits its fetch reads back) per output token."""
 
 
 def read(run):
     tokens = run.window_tokens()
-    if not run.named("bench.forward") or not tokens:
+    d2h = run.window.counters.get("d2h_bytes")
+    if d2h is None or not tokens:
         return None
-    return run.window.counters["out_bytes"] / tokens
+    return d2h / tokens

@@ -62,11 +62,13 @@ def _layer_params(params, i, dtype):
     return {k: params[v].astype(dtype) for k, v in names.items()}
 
 
-def logits(params, lm, rows: np.ndarray, dtype=jnp.float32) -> jax.Array:
+def logits(params, lm, rows: np.ndarray, dtype=jnp.float32,
+           ids=None) -> jax.Array:
     """Logits at every position of one sequence whose embedded rows are
     ``rows`` ``(S, d_model)``: ``(S', vocab)`` on the device, in ``dtype``,
     where ``S'`` pads ``S`` to a multiple of ``PAD`` (the padded rows come
-    after the sequence and change none of its logits)."""
+    after the sequence and change none of its logits).  The token ``ids``
+    are not needed: the embedding is outside the model."""
     prec = (jax.lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
             else jax.lax.Precision.DEFAULT)
     s = rows.shape[0]

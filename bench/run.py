@@ -48,13 +48,17 @@ def use_bench_compile_cache(bench: Path = BENCH) -> str:
     """JAX's persistent compilation cache in the benchmark's own fixed
     directory in the checkout, whatever directory the machine's environment
     names, so that two checkouts never share compiled programs.  The
-    program's ``use_compile_cache`` takes it from the environment."""
+    program's ``use_compile_cache`` takes it from the environment.  The
+    cache is unbounded, whatever size the environment sets: under a bound
+    JAX reads every entry's size and access time on each write and locks a
+    file on each read, and a first run writes some 800 programs."""
     cache_dir = str(bench / ".cache" / "jax")
     os.environ["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     import jax
     from repro.launch.compile_cache import use_compile_cache
     jax.config.update("jax_compilation_cache_dir", use_compile_cache())
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_max_size", -1)
     return cache_dir
 
 
@@ -169,11 +173,10 @@ def run(args, *, root: Path = BENCH.parent, backend: str = "pallas_tpu",
     lm = cell.lm
     cell.free()
     del rec
-    from harness import weights
     ref = spec.reference(lm["reference"])
     t0 = time.perf_counter()
-    params = weights.make_params(lm, args.seed)
-    embed = weights.embedding(lm, args.seed)
+    params = cell.params(args.seed)
+    embed = cell.embedding(args.seed)
     readings = check.compare(ref, lm, params, embed, sample)
     del params
     checks = check.verdict(readings, w.compiles, limits)

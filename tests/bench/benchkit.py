@@ -11,7 +11,8 @@ sys.path.insert(0, str(REPO / "bench"))
 
 TINY_LM = {"d_model": 32, "n_layers": 2, "n_heads": 4, "n_kv_heads": 2,
            "d_ff": 64, "vocab": 64, "dtype": "float32",
-           "matmul_precision": "default", "reference": "pre_ln_gelu_lm"}
+           "matmul_precision": "default", "model": "pre_ln_gelu_lm",
+           "reference": "pre_ln_gelu_lm"}
 
 
 class Checkout:

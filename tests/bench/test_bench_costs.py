@@ -1,5 +1,6 @@
 """The yardstick's arithmetic against numbers worked out by hand, for both
-configurations, and the peaks table."""
+configurations (the ``pre_ln_gelu_lm`` model module's counts), and the
+peaks table."""
 import dataclasses
 import json
 
@@ -7,11 +8,12 @@ import numpy as np
 import pytest
 from benchkit import REPO
 
-from harness import costs, peaks, weights
+from harness import peaks, weights
 from harness.readers import RunData
 from harness.spec import Spec, lm_widths
 
 SPEC = Spec(REPO)
+costs = SPEC.model("pre_ln_gelu_lm")
 QWEN, GPT2 = (lm_widths(json.loads((REPO / f"bench/configs/{c}.json")
                                    .read_text()))
               for c in ("qwen2-1.5b-widths", "gpt2-large"))
@@ -24,7 +26,8 @@ def test_parameter_count(lm, params):
     v 2 x 393,216, the MLP 2 x 13,762,560 + 10,496 of biases; the head
     151,936 x 1,536 + 151,936.  GPT-2 large: 19,672,320 a layer x 36 and a
     head of 50,257 x 1,280 + 50,257."""
-    total = sum(int(np.prod(s)) for s in weights.shapes(lm).values())
+    total = sum(int(np.prod(s))
+                for s in weights.shapes(costs.weights(lm)).values())
     assert total == params
 
 
@@ -68,6 +71,7 @@ class _Win:
 @dataclasses.dataclass
 class _Cell:
     lm: dict
+    arch: object = costs
 
 
 def test_mfu_counts_real_tokens_inside_the_window():

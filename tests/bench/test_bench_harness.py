@@ -1,6 +1,7 @@
 """The harness end to end on the CPU at tiny widths: driven by data, every
 mix, the faults the check must catch, and no result without a chip."""
 import json
+import os
 import subprocess
 import sys
 
@@ -82,6 +83,31 @@ def test_no_accelerator_no_result(tmp_path):
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "needs a TPU" in proc.stderr
+
+
+def test_compile_cache_in_the_checkout_and_unbounded(tmp_path):
+    """The benchmark's compilation cache is its own directory, without the
+    bound a machine's environment may set: under a bound JAX scans every
+    entry on each write, which made a first run's tuning three times as
+    long on the chip."""
+    code = ("import sys; sys.path.insert(0, sys.argv[1]); import jax, run; "
+            "d = run.use_bench_compile_cache(run.Path(sys.argv[2])); "
+            "jax.jit(lambda x: x * 3 + 1)(jax.numpy.ones(5)).block_until_ready(); "
+            "print(d, jax.config.jax_compilation_cache_max_size)")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "elsewhere"),
+               JAX_COMPILATION_CACHE_MAX_SIZE="201326592")
+    proc = subprocess.run(
+        [sys.executable, "-c", code, str(REPO / "bench"),
+         str(tmp_path / "bench")], capture_output=True, text=True,
+        timeout=120, env=env)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    cache = tmp_path / "bench" / ".cache" / "jax"
+    assert proc.stdout.split() == [str(cache), "-1"]
+    names = [p.name for p in cache.iterdir()]
+    assert any(n.endswith("-cache") for n in names)
+    assert not any(n.endswith("-atime") for n in names)     # no eviction
+    assert not (tmp_path / "elsewhere").exists()
 
 
 def test_same_seed_same_inputs_and_every_seed_same_sizes():

@@ -11,6 +11,7 @@ from harness.spec import Spec
 
 SPEC = Spec(REPO)
 REF = SPEC.reference("pre_ln_gelu_lm")
+ARCH = SPEC.model("pre_ln_gelu_lm")
 MHA64 = dict(TINY_LM, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256,
              vocab=128)
 
@@ -26,9 +27,9 @@ class _Served:
 def _serve(lm, seed, prompts, gen, limits):
     """Serve ``prompts`` greedily; every request's logits at every served
     position, in order."""
-    m = model.build(lm)
-    model.load_seeded(m, lm, seed)
-    srv = model.server(lm, limits, m, weights.embedding(lm, seed), "xla")
+    m = model.build_seeded(ARCH, lm, seed)
+    srv = model.server(ARCH, lm, limits, m, weights.embedding(lm, seed),
+                       "xla")
     reqs = [srv.submit(p, gen) for p in prompts]
     srv.warm_autotune()
     seen = {r.rid: [] for r in reqs}
@@ -49,7 +50,7 @@ def test_served_prefill_and_decode_logits_match_the_reference(
                for n in (5, 11, 3)]
     reqs, seen = _serve(lm, seed, prompts, 6,
                         {"max_seq": 32, "max_batch": 2, "slots": 3})
-    params = weights.make_params(lm, seed)
+    params = weights.make_params(ARCH.weights(lm), seed)
     embed = weights.embedding(lm, seed)
     for r in reqs:
         seq = np.concatenate([r.prompt, np.asarray(r.generated[:-1])])
@@ -79,7 +80,7 @@ def test_control_fails_where_the_program_passes(fast_autotune):
                for n in (20, 9, 14, 4)]
     reqs, seen = _serve(lm, seed, prompts, 24,
                         {"max_seq": 64, "max_batch": 4, "slots": 4})
-    params = weights.make_params(lm, seed)
+    params = weights.make_params(ARCH.weights(lm), seed)
     got = check.compare(REF, lm, params, weights.embedding(lm, seed),
                         [_Served(r, seen[r.rid]) for r in reqs],
                         control=True)

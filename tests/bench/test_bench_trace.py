@@ -92,3 +92,121 @@ def test_recorded_v5e_trace_slice():
     assert s["busy_s"] + idle == pytest.approx(s["window_s"], rel=1e-9)
     name, _ = s["device_ops"][0]
     assert name.startswith("reshape u8[29366304,4] @ ")
+
+
+# -- the scope of each device operation ---------------------------------------
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        b, n = n & 0x7F, n >> 7
+        out.append(b | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _f(num: int, v) -> bytes:
+    """One protobuf field: a varint, or a length-delimited string/message."""
+    if isinstance(v, int):
+        return _varint(num << 3) + _varint(v)
+    v = v.encode() if isinstance(v, str) else v
+    return _varint(num << 3 | 2) + _varint(len(v)) + v
+
+
+def _stat(meta: int, text=None, ref=None) -> bytes:
+    return _f(1, meta) + (_f(5, text) if text is not None else _f(7, ref))
+
+
+def _event(meta: int, off_ps: int, dur_ps: int, *stats) -> bytes:
+    return (_f(1, meta) + _f(2, off_ps) + _f(3, dur_ps)
+            + b"".join(_f(4, s) for s in stats))
+
+
+def _plane(pid, name, lines, event_meta, stat_meta) -> bytes:
+    return (_f(1, pid) + _f(2, name)
+            + b"".join(_f(3, _f(2, ln) + _f(3, ts)
+                          + b"".join(_f(4, e) for e in evs))
+                       for ln, ts, evs in lines)
+            + b"".join(_f(4, _f(1, k) + _f(2, _f(1, k) + v))
+                       for k, v in event_meta.items())
+            + b"".join(_f(5, _f(1, k) + _f(2, _f(1, k) + _f(2, v)))
+                       for k, v in stat_meta.items()))
+
+
+def _xspace() -> bytes:
+    """A host plane with the window span, and a device plane whose ``XLA
+    Ops`` events carry their scope as the metadata's ``tf_op`` stat (as a
+    string, or as a reference to a stat metadata entry that holds it) or
+    not at all; an event's own stats do not name its scope."""
+    stat_meta = {1: "tf_op", 2: "jit(f)/linear:pallas.mm/dot_general"}
+    event_meta = {
+        10: _f(2, "%fusion.1 = f32[4]{0} fusion()")
+        + _f(5, _stat(1, text="jit(f)/matmul:ref/dot_general")),
+        11: _f(2, "%copy.2") + _f(5, _stat(1, ref=2)),
+        12: _f(2, "%custom-call.3")}
+    ops = [_event(10, 0, 10**9), _event(11, 2 * 10**9, 5 * 10**8),
+           _event(12, 3 * 10**9, 7 * 10**9),
+           _event(11, 9 * 10**9, 10**6, _stat(1, text="jit(g)/gelu:x"))]
+    device = _plane(3, "/device:TPU:0",
+                    [("XLA Modules", 1000, [_event(12, 0, 5000)]),
+                     ("XLA Ops", 1000, ops)], event_meta, stat_meta)
+    host = _plane(1, "/host:CPU", [("python", 0, [_event(5, 0, 10**10)])],
+                  {5: _f(2, "bench.window")}, {})
+    return _f(1, host) + _f(1, device)
+
+
+def test_load_keeps_each_operation_s_scope(tmp_path):
+    d = tmp_path / "plugins" / "profile" / "run"
+    d.mkdir(parents=True)
+    (d / "host.xplane.pb").write_bytes(_xspace())
+    rec = trace.load(str(tmp_path))
+    assert rec["spans"] == [["bench.window", 0.0, 10.0 * MS]]
+    assert rec["ops"]["/device:TPU:0"] == [
+        ["%fusion.1 = f32[4]{0} fusion()", 1000.0, 1.0 * MS,
+         "jit(f)/matmul:ref/dot_general"],
+        ["%copy.2", 1000.0 + 2 * MS, 0.5 * MS,
+         "jit(f)/linear:pallas.mm/dot_general"],
+        ["%custom-call.3", 1000.0 + 3 * MS, 7.0 * MS, ""],
+        ["%copy.2", 1000.0 + 9 * MS, 1000.0,
+         "jit(f)/linear:pallas.mm/dot_general"]]
+
+
+def test_scope_seconds_by_node_kind_any_implementation():
+    rec = {"spans": [["bench.window", 0, 10 * MS]], "ops": {
+        "/device:TPU:0": [
+            ["a", 1 * MS, 2 * MS, "jit(run)/jit(fn)/linear:pallas.mm/dot"],
+            ["b", 3 * MS, 1 * MS, "jit(run)/jit(fn)/linear:ref.linear"],
+            ["c", 4 * MS, 1 * MS, "jit(run)/jit(fn)/matmul:ref.matmul/x"],
+            ["d", 5 * MS, 1 * MS, "jit(run)/decode_attention:ref/x"],
+            ["e", 6 * MS, 1 * MS, ""],
+            ["f", 11 * MS, 1 * MS, "jit(run)/linear:pallas.mm"]]}}
+    assert trace.scope_seconds(rec, ["linear"]) == pytest.approx(0.003)
+    assert trace.scope_seconds(rec, ["linear", "matmul"]) == \
+        pytest.approx(0.004)
+    assert trace.scope_seconds(rec, ["attention"]) == 0.0
+    assert trace.scope_seconds(rec, ["decode_attention"]) == \
+        pytest.approx(0.001)
+
+
+def test_prefetch_seconds_by_weight_shape():
+    """Unscoped asynchronous copies of a weight matrix or of a block of its
+    rows count; a copy of another shape, another op, a scoped copy or one
+    after the window does not."""
+    rec = {"spans": [["bench.window", 0, 10 * MS]], "ops": {
+        "/device:TPU:0": [
+            ["%async-done.1 = f32[384,8960]{1,0:T(8,128)} async-done("
+             "%async-start.1)", 1 * MS, 2 * MS, ""],
+            ["%copy-start.2 = (f32[1536,256]{1,0}, f32[1536,256]{1,0}, "
+             "u32[]) copy-start(f32[1536,256]{1,0} %p)", 3 * MS, MS, ""],
+            ["async-done f32[384,1536]", 4 * MS, MS, ""],
+            ["%copy-done.3 = f32[1,512,2,128]{3,2,1,0} copy-done(%c)",
+             5 * MS, MS, ""],
+            ["%fusion.4 = f32[384,8960]{1,0} fusion(%x)", 6 * MS, MS, ""],
+            ["async-done f32[384,8960]", 7 * MS, MS,
+             "jit(run)/linear:ref.linear"],
+            ["async-done f32[384,8960]", 11 * MS, MS, ""]]}}
+    mats = {(1536, 8960), (1536, 256)}
+    assert trace.prefetch_seconds(rec, mats) == pytest.approx(0.003)
+    assert trace.prefetch_seconds(rec, mats | {(8960, 1536)}) == \
+        pytest.approx(0.004)
+    assert trace.prefetch_seconds(rec, set()) == 0.0
