@@ -212,7 +212,7 @@ _ENTRY_POINTS_STATE = "unloaded"     # unloaded | loading | loaded
 
 def _load_entry_points() -> None:
     """Import the modules that populate the dispatch table: the executor's
-    reference lowerings and the five kernel entry points (each ops.py
+    reference lowerings and the kernel families' entry points (each ops.py
     registers its own impls at import).  A failed import resets the state so
     the real error resurfaces on the next dispatch call instead of leaving a
     silently half-populated table."""
@@ -228,6 +228,7 @@ def _load_entry_points() -> None:
         from ..kernels.dfp_fused import ops as _d            # noqa: F401
         from ..kernels.flash_attention import ops as _f      # noqa: F401
         from ..kernels.matmul import ops as _m               # noqa: F401
+        from ..kernels.moe import ops as _moe                # noqa: F401
         from ..kernels.rglru_scan import ops as _g           # noqa: F401
         from ..kernels.rwkv6_scan import ops as _r           # noqa: F401
         # backward entry points (each grad.py registers its impls at import)

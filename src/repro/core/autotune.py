@@ -165,8 +165,9 @@ def pad_shape(shape: Sequence[int]) -> Tuple[int, ...]:
 
 
 def node_shape(node) -> Optional[Tuple[int, ...]]:
-    """The shape a node is keyed under.  LINEAR/MATMUL → (M, K, N) with
-    leading batch dims folded into M; DECODE_ATTENTION → (B, S, H, hd) from
+    """The shape a node is keyed under.  LINEAR/MATMUL/MOE → (M, K, N) with
+    leading batch dims folded into M (a MOE node's work depends on its rows
+    alone); DECODE_ATTENTION → (B, S, H, hd) from
     the KV-cache operand, so each decode cache bucket gets its own timings
     (the output shape is (B, 1, H, hd) for *every* cache length and would
     alias all buckets); everything else → the output shape."""
@@ -177,7 +178,7 @@ def node_shape(node) -> Optional[Tuple[int, ...]]:
         b, _one, h, hd = node.spec.shape
         s = node.inputs[1].spec.shape[1]          # k_cache is (B, S, KV, hd)
         return (b, s, h, hd)
-    if node.op in (OpKind.LINEAR, OpKind.MATMUL):
+    if node.op in (OpKind.LINEAR, OpKind.MATMUL, OpKind.MOE):
         xs = node.inputs[0].spec.shape if node.inputs else ()
         if not xs or not node.spec.shape:
             return None

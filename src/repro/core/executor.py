@@ -68,6 +68,25 @@ def _lower_conv2d(n: Node, x: Array, w: Array, b: Array | None,
     return y
 
 
+def _lower_rope(n: Node, x: Array, start: Array | None) -> Array:
+    """Rotary embedding of x (B, S, H, hd), ``rotate_half`` layout: the
+    position of row ``s`` is ``s``, or ``start[b] + s`` where a start is
+    given (a decode step's cache length); ``attrs['inv_freq']`` holds the
+    hd/2 frequencies and ``attrs['scale']`` the factor on cos and sin."""
+    s = x.shape[1]
+    pos = jnp.arange(s)[None, :]
+    if start is not None:
+        pos = start[:, None] + pos
+    ang = pos.astype(jnp.float32)[..., None] * jnp.asarray(
+        n.attrs["inv_freq"], jnp.float32)
+    scale = n.attrs.get("scale", 1.0)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, :, None, :] * scale
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, :, None, :] * scale
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (x * cos + rot * sin).astype(x.dtype)
+
+
 def _pool(n: Node, x: Array, reduce_fn, init) -> Array:
     k = n.attrs.get("kernel", 2)
     s = n.attrs.get("stride", k)
@@ -118,6 +137,10 @@ def _lower_node(n: Node, vals: List[Array], backend: "registry.Backend"
     if op is OpKind.TIME_SHIFT:
         x = vals[0]
         return jnp.concatenate([jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
+    if op is OpKind.ROPE:
+        return _lower_rope(n, vals[0], vals[1] if len(vals) > 1 else None)
+    if op is OpKind.STACK:
+        return jnp.stack(vals)
     if op is OpKind.SOFTCAP:
         c = n.attrs["cap"]
         return jnp.tanh(vals[0] / c) * c
@@ -200,7 +223,8 @@ def compose_fused(n: Node, vals: Sequence[Array],
 _REFERENCE_OPS = (
     list(_ELEMENTWISE)
     + [OpKind.ADD, OpKind.SUB, OpKind.MUL, OpKind.DIV, OpKind.BIAS_ADD,
-       OpKind.SCALE, OpKind.SQRT, OpKind.TIME_SHIFT, OpKind.SOFTCAP,
+       OpKind.SCALE, OpKind.SQRT, OpKind.TIME_SHIFT, OpKind.ROPE,
+       OpKind.STACK, OpKind.SOFTCAP,
        OpKind.MAXPOOL, OpKind.AVGPOOL,
        OpKind.GLOBALPOOL, OpKind.LAYERNORM, OpKind.RMSNORM, OpKind.BATCHNORM,
        OpKind.SOFTMAX, OpKind.DROPOUT, OpKind.FLATTEN, OpKind.RESHAPE,

@@ -96,6 +96,7 @@ class OpKind(enum.Enum):
     DECODE_ATTENTION = "decode_attention"  # 1 query vs a paged KV cache
     RGLRU_SCAN = "rglru_scan"     # gated linear recurrence h_t = a·h + b
     RWKV6_SCAN = "rwkv6_scan"     # RWKV6 WKV recurrence
+    MOE = "moe"                   # routed SwiGLU experts, a held share
     # DFP-module ops (memory-bound → fused depth-first code)
     RELU = "relu"
     GELU = "gelu"
@@ -106,6 +107,9 @@ class OpKind(enum.Enum):
     SOFTPLUS = "softplus"
     SQRT = "sqrt"             # optional 'min' attr clamps before the root
     TIME_SHIFT = "time_shift" # prev-token features along axis 1 (zeros at t=0)
+    ROPE = "rope"             # rotary position embedding of (B, S, H, hd)
+    MOE_COUNT = "moe_count"   # int32 (pairs, touched) of one MOE node
+    STACK = "stack"           # stack same-shape inputs on a new axis 0
     ADD = "add"
     SUB = "sub"
     MUL = "mul"
@@ -148,6 +152,10 @@ DFP_FUSABLE = {
 # nodes through the dispatch table (attention + linear-recurrence scans).
 SEQUENCE_OPS = {OpKind.ATTENTION, OpKind.DECODE_ATTENTION,
                 OpKind.RGLRU_SCAN, OpKind.RWKV6_SCAN}
+
+# Whole-node kernels elected per node through the dispatch table: the DNN
+# module's share of the graph (never DFP-fused).
+DNN_OPS = {OpKind.LINEAR, OpKind.MATMUL, OpKind.MOE} | SEQUENCE_OPS
 
 # Source nodes carry no inputs; everything else must have at least one.
 SOURCE_OPS = {OpKind.INPUT, OpKind.PARAM, OpKind.CONST}

@@ -31,8 +31,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
-from .ir import (DFP_FUSABLE, SEQUENCE_OPS, SOURCE_OPS, Graph, Module, Node,
-                 OpKind, TensorSpec)
+from .ir import (DFP_FUSABLE, DNN_OPS, SEQUENCE_OPS, SOURCE_OPS, Graph,
+                 Module, Node, OpKind, TensorSpec)
 
 
 # ----------------------------------------------------------------------------
@@ -109,9 +109,10 @@ def assign_modules(g: Graph) -> Graph:
     for n in g.topo():
         if n.op in SOURCE_OPS or n.op is OpKind.OUTPUT:
             continue
-        if n.op in (OpKind.LINEAR, OpKind.MATMUL) or n.op in SEQUENCE_OPS:
-            # sequence kernels (attention, linear-recurrence scans) are
-            # whole-node dispatch-table ops, like the matmul family
+        if n.op in DNN_OPS:
+            # sequence kernels (attention, linear-recurrence scans) and the
+            # routed experts are whole-node dispatch-table ops, like the
+            # matmul family
             n.module = Module.DNN
         elif n.op is OpKind.CONV2D:
             groups = n.attrs.get("groups", 1)
@@ -311,6 +312,14 @@ def _node_cost_terms(n: Node) -> Tuple[float, float, float]:
         flops = 4.0 * b * h * (s + 1) * hd
         score_bytes = 2.0 * b * h * s * 4.0
         return flops, streamed, streamed + score_bytes
+    if n.op is OpKind.MOE:
+        # rows · top_k pairs, the held share (n_held / n_experts) of them on
+        # average, 6·d·f FLOPs each (gate, up, down); every held expert's
+        # weights read once at most
+        n_held, d, f = n.inputs[2].spec.shape
+        rows = n.spec.size // d
+        pairs = rows * n.attrs["top_k"] * n_held / n.inputs[1].spec.shape[-1]
+        return 6.0 * pairs * d * f, streamed, streamed
     if n.op is OpKind.RGLRU_SCAN:
         # h_t = a·h + b: ~2 FLOPs/element; streamed bytes dominate either way
         return 2.0 * n.spec.size, streamed, streamed

@@ -81,13 +81,15 @@ def sol_bound_us(hw, flops: float, nbytes: float) -> Tuple[float, str]:
 
 def sol_ratio(us: float, bound_us: float) -> float:
     """measured ÷ bound, guarded to be finite and ≥ 0 for ANY cache entry:
-    a missing bound (0.0) or non-finite measurement yields 0.0 — such a
-    row ranks as 'no known gap', never as an infinite one."""
+    a missing bound (0.0), a non-finite measurement, or a bound so small
+    that the quotient overflows yields 0.0 — such a row ranks as 'no known
+    gap', never as an infinite one."""
     if bound_us <= 0.0 or not math.isfinite(bound_us):
         return 0.0
     if us < 0.0 or not math.isfinite(us):
         return 0.0
-    return us / bound_us
+    ratio = us / bound_us
+    return ratio if math.isfinite(ratio) else 0.0
 
 
 def cache_rows(cache, *, backends: Optional[Sequence[str]] = None,

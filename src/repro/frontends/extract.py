@@ -127,6 +127,8 @@ class EmitContext:
         self.kv_outputs: List[Node] = []   # per-layer (k, v) rows, in layer
                                            # order, aligned with kv_inputs
         self.lens: Node | None = None      # decode: (B,) int32 cache lengths
+        self.counters: List[Node] = []     # prefill/decode: per-MOE-layer
+                                           # int32 (pairs, touched)
 
     def emit(self, m: nn.Module, x: Node, path: str = "") -> Node:
         if self.mode == "decode":
@@ -301,6 +303,13 @@ def _emit_layernorm(m: nn.LayerNorm, ctx, x, path):
                 TensorSpec(x.spec.shape, ctx.dtype))
 
 
+@register_emitter(nn.RMSNorm)
+def _emit_rmsnorm(m: nn.RMSNorm, ctx, x, path):
+    g = ctx.param(path + "weight", m._params["weight"])
+    return Node(OpKind.RMSNORM, [x, g], TensorSpec(x.spec.shape, ctx.dtype),
+                attrs={"eps": m.eps})
+
+
 @register_emitter(nn.BatchNorm2d)
 def _emit_batchnorm(m: nn.BatchNorm2d, ctx, x, path):
     ps = [ctx.param(path + n, m._params[n]) for n in
@@ -318,6 +327,18 @@ def _emit_dropout(m: nn.Dropout, ctx, x, path):
 # sequence-layer emitters: ATTENTION / RGLRU_SCAN / RWKV6_SCAN
 # ---------------------------------------------------------------------------
 
+def _rope(m: nn.MultiHeadAttention, ctx: EmitContext, x: Node,
+          start: Node | None = None) -> Node:
+    """``x`` (B, S, H, hd) with the layer's rotary embedding applied: row
+    ``s`` at position ``s`` (a prompt), or ``start[b]`` (a decode step at
+    cache length ``start``); ``x`` itself without one."""
+    if m.rope is None:
+        return x
+    return Node(OpKind.ROPE, [x] + ([start] if start is not None else []),
+                TensorSpec(x.spec.shape, ctx.dtype),
+                attrs={"inv_freq": m.rope.inv_freq, "scale": m.rope.scale})
+
+
 @register_emitter(nn.MultiHeadAttention)
 def _emit_attention(m: nn.MultiHeadAttention, ctx: EmitContext, x: Node,
                     path: str) -> Node:
@@ -329,6 +350,7 @@ def _emit_attention(m: nn.MultiHeadAttention, ctx: EmitContext, x: Node,
                     (b, s, m.n_kv_heads, hd))
     v = ctx.reshape(ctx.matmul(x, ctx.param(path + "wv", m._params["wv"])),
                     (b, s, m.n_kv_heads, hd))
+    q, k = _rope(m, ctx, q), _rope(m, ctx, k)
     att = Node(OpKind.ATTENTION, [q, k, v],
                TensorSpec((b, s, m.n_heads, hd), ctx.dtype),
                attrs={"causal": m.causal, "window": m.window, "cap": m.cap})
@@ -363,6 +385,7 @@ def _emit_attention_decode(m: nn.MultiHeadAttention, ctx: EmitContext,
     v_new = ctx.reshape(
         ctx.matmul(x, ctx.param(path + "wv", m._params["wv"])),
         (b, 1, m.n_kv_heads, hd))
+    q, k_new = _rope(m, ctx, q, ctx.lens), _rope(m, ctx, k_new, ctx.lens)
     cshape = (b, ctx.max_seq, m.n_kv_heads, hd)
     k_cache = ctx.kv_input(cshape, name=f"{path}k_cache")
     v_cache = ctx.kv_input(cshape, name=f"{path}v_cache")
@@ -373,6 +396,20 @@ def _emit_attention_decode(m: nn.MultiHeadAttention, ctx: EmitContext,
     ctx.kv_outputs += [k_new, v_new]
     o = ctx.reshape(att, (b, 1, m.n_heads * hd))
     return ctx.matmul(o, ctx.param(path + "wo", m._params["wo"]))
+
+
+@register_emitter(nn.MoE)
+def _emit_moe(m: nn.MoE, ctx: EmitContext, x: Node, path: str) -> Node:
+    """One MOE node over the rows; in the serving programs also its
+    MOE_COUNT, which the programs return beside the logits."""
+    ps = [ctx.param(path + k, m._params[k])
+          for k in ("router", "w_gate", "w_up", "w_down")]
+    if ctx.mode in ("prefill", "decode"):
+        ctx.counters.append(Node(
+            OpKind.MOE_COUNT, [x, ps[0]], TensorSpec((2,), "int32"),
+            attrs=dict(m.attrs, n_held=m.held)))
+    return Node(OpKind.MOE, [x] + ps, TensorSpec(x.spec.shape, ctx.dtype),
+                attrs=dict(m.attrs))
 
 
 @register_emitter(nn.RGLRU)
@@ -466,17 +503,28 @@ def extract(model: nn.Module, input_shape: Tuple[int, ...],
     return g
 
 
+def _counts(ctx: EmitContext) -> List[Node]:
+    """The serving programs' last output where the model has MOE layers:
+    their (pairs, touched), stacked in layer order, int32 (layers, 2)."""
+    if not ctx.counters:
+        return []
+    return [Node(OpKind.STACK, list(ctx.counters),
+                 TensorSpec((len(ctx.counters), 2), "int32"))]
+
+
 def extract_prefill(model: nn.Module, input_shape: Tuple[int, ...],
                     dtype: str = "float32") -> Graph:
     """The serving prefill program: identical compute to :func:`extract`,
     but every attention layer's (k, v) projections join the graph outputs —
     ``outputs = [logits, k_0, v_0, k_1, v_1, ...]`` in layer order — so one
     prompt forward both produces next-token logits and seeds the request's
-    KV-cache slot."""
+    KV-cache slot.  A model with MOE layers adds their counts last
+    (:func:`_counts`)."""
     x = ir.input_node(input_shape, dtype, ir.BSD(), name="input")
     ctx = EmitContext(dtype, mode="prefill")
     out = ctx.emit(model, x, "")
-    g = Graph(inputs=[x], outputs=[out] + ctx.kv_outputs, params=ctx.params)
+    g = Graph(inputs=[x], outputs=[out] + ctx.kv_outputs + _counts(ctx),
+              params=ctx.params)
     g.validate()
     return g
 
@@ -486,7 +534,8 @@ def extract_decode(model: nn.Module, batch: int, max_seq: int,
     """The serving decode program: one token per resident sequence.
 
     ``inputs  = [x (B, 1, D), lens (B,) int32, k_cache_0, v_cache_0, ...]``
-    ``outputs = [logits (B, 1, V), k_new_0, v_new_0, ...]``
+    ``outputs = [logits (B, 1, V), k_new_0, v_new_0, ...]``, and the MOE
+    layers' counts last where the model has any (:func:`_counts`).
 
     Cache inputs are (B, max_seq, KV, hd) with rows ``[0, lens[b])`` valid;
     the new (k, v) outputs are the rows the server appends at position
@@ -498,6 +547,7 @@ def extract_decode(model: nn.Module, batch: int, max_seq: int,
     ctx.lens = lens
     out = ctx.emit(model, x, "")
     g = Graph(inputs=[x, lens] + ctx.kv_inputs,
-              outputs=[out] + ctx.kv_outputs, params=ctx.params)
+              outputs=[out] + ctx.kv_outputs + _counts(ctx),
+              params=ctx.params)
     g.validate()
     return g

@@ -192,6 +192,17 @@ class LayerNorm(Module):
                                 self._params["bias"])
 
 
+class RMSNorm(Module):
+    def __init__(self, dim: int, eps: float = 1e-6):
+        super().__init__()
+        self.dim = dim
+        self.eps = eps
+        self.register("weight", jnp.ones((dim,)))
+
+    def forward(self, x: Array) -> Array:
+        return _eager_rmsnorm(x, self._params["weight"], self.eps)
+
+
 class BatchNorm2d(Module):
     def __init__(self, ch: int):
         super().__init__()
@@ -250,24 +261,72 @@ class Residual(Sequential):
 # ATTENTION / RGLRU_SCAN / RWKV6_SCAN graph nodes so the dispatch table can
 # elect the Pallas kernels (see frontends/extract.py).
 
+class Rope:
+    """Rotary position embedding tables of one attention layer: the
+    ``head_dim // 2`` inverse frequencies (float32, as transformers'
+    ``rope_utils`` computes them) and the factor on cos and sin.  Applied in
+    the ``rotate_half`` layout to q and k at each row's position."""
+
+    def __init__(self, inv_freq, scale: float = 1.0):
+        self.inv_freq = tuple(float(v) for v in
+                              np.asarray(inv_freq, np.float32))
+        self.scale = float(scale)
+
+    @classmethod
+    def default(cls, head_dim: int, theta: float) -> "Rope":
+        exps = np.arange(0, head_dim, 2, dtype=np.float64) / head_dim
+        return cls(1.0 / theta ** exps)
+
+    @classmethod
+    def yarn(cls, head_dim: int, theta: float, factor: float,
+             original_max_positions: int, beta_fast: float,
+             beta_slow: float, attention_factor: float) -> "Rope":
+        """YaRN: frequencies whose wavelength fits ``beta_fast`` turns in
+        the original context keep their value, those under ``beta_slow``
+        turns are divided by ``factor``, and a linear ramp joins the two
+        (over dimension indices rounded outwards); cos and sin are scaled
+        by ``attention_factor``."""
+        def corr_dim(turns: float) -> float:
+            return (head_dim * math.log(original_max_positions
+                                        / (turns * 2 * math.pi))
+                    / (2 * math.log(theta)))
+        low = max(math.floor(corr_dim(beta_fast)), 0)
+        high = min(math.ceil(corr_dim(beta_slow)), head_dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                       / (high - low), 0.0, 1.0)
+        extrapolated = 1.0 - ramp
+        pos_freqs = theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                              / head_dim)
+        inv_freq = (1.0 / (factor * pos_freqs) * (1.0 - extrapolated)
+                    + 1.0 / pos_freqs * extrapolated)
+        return cls(inv_freq, attention_factor)
+
+
 class MultiHeadAttention(Module):
-    """Bias-free multi-head attention with GQA, sliding window and logit
-    softcap.  Weights are stored (in, out) — the sequence layers follow the
-    io layout so projections extract as MATMUL nodes."""
+    """Bias-free multi-head attention with GQA, sliding window, logit
+    softcap and an optional rotary embedding (``rope``) of q and k.
+    ``head_dim`` defaults to ``d_model // n_heads``.  Weights are stored
+    (in, out) — the sequence layers follow the io layout so projections
+    extract as MATMUL nodes."""
 
     def __init__(self, d_model: int, n_heads: int,
                  n_kv_heads: Optional[int] = None, causal: bool = True,
-                 window: int = 0, cap: float = 0.0):
+                 window: int = 0, cap: float = 0.0,
+                 head_dim: Optional[int] = None,
+                 rope: Optional[Rope] = None):
         super().__init__()
-        if d_model % n_heads:
+        if head_dim is None and d_model % n_heads:
             raise ValueError(f"d_model {d_model} not divisible by {n_heads}")
         self.d_model = d_model
         self.n_heads = n_heads
         self.n_kv_heads = n_kv_heads or n_heads
         if n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
-        self.head_dim = d_model // n_heads
+        self.head_dim = head_dim or d_model // n_heads
         self.causal, self.window, self.cap = causal, window, cap
+        self.rope = rope
         hd = self.head_dim
         self.register("wq", _kaiming(_next_key(), (d_model, n_heads * hd),
                                      d_model))
@@ -289,8 +348,52 @@ class MultiHeadAttention(Module):
             b, s, self.n_kv_heads, hd)
         v = jnp.einsum("bsd,de->bse", x, p["wv"]).reshape(
             b, s, self.n_kv_heads, hd)
+        if self.rope is not None:
+            q = _eager_rope(q, self.rope.inv_freq, self.rope.scale)
+            k = _eager_rope(k, self.rope.inv_freq, self.rope.scale)
         o = flash_mha(q, k, v, self.causal, self.window, self.cap)
         return jnp.einsum("bse,ed->bsd", o.reshape(b, s, -1), p["wo"])
+
+
+class MoE(Module):
+    """Routed SwiGLU experts: the router scores every row over all
+    ``n_experts``, the ``top_k`` best are kept (renormalised over them
+    where ``norm_topk``), and each routed expert adds ``down(silu(gate(x))
+    · up(x))`` at its weight.  The module holds ``held`` experts, ``first
+    .. first + held - 1`` (all of them by default), and adds only their
+    part: the share of the layer one chip holds under expert parallelism.
+    Weights: ``router`` (d, n_experts); ``w_gate``, ``w_up`` (held, d, f);
+    ``w_down`` (held, f, d)."""
+
+    def __init__(self, d_model: int, d_expert: int, n_experts: int,
+                 top_k: int, *, held: Optional[int] = None, first: int = 0,
+                 norm_topk: bool = True, score: str = "softmax"):
+        super().__init__()
+        held = n_experts - first if held is None else held
+        if first < 0 or held < 1 or first + held > n_experts:
+            raise ValueError(f"experts {first}..{first + held - 1} are not "
+                             f"among the {n_experts}")
+        if not 1 <= top_k <= n_experts:
+            raise ValueError(f"top_k {top_k} outside 1..{n_experts}")
+        self.d_model, self.d_expert = d_model, d_expert
+        self.n_experts, self.held = n_experts, held
+        self.attrs = dict(top_k=top_k, first=first, norm_topk=norm_topk,
+                          score=score)
+        self.register("router", _kaiming(_next_key(), (d_model, n_experts),
+                                         d_model))
+        for name, shape, fan in (("w_gate", (held, d_model, d_expert),
+                                  d_model),
+                                 ("w_up", (held, d_model, d_expert), d_model),
+                                 ("w_down", (held, d_expert, d_model),
+                                  d_expert)):
+            self.register(name, _kaiming(_next_key(), shape, fan))
+
+    def forward(self, x: Array) -> Array:
+        from ..kernels.moe.ref import moe_ref
+        p = self._params
+        y = moe_ref(x.reshape(-1, self.d_model), p["router"], p["w_gate"],
+                    p["w_up"], p["w_down"], **self.attrs)
+        return y.reshape(x.shape)
 
 
 class RGLRU(Module):
@@ -398,6 +501,21 @@ def _eager_layernorm(x, g, b):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
     return (x - mu) * jax.lax.rsqrt(var + 1e-5) * g + b
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
+def _eager_rmsnorm(x, g, eps):
+    return x * jax.lax.rsqrt((x * x).mean(-1, keepdims=True) + eps) * g
+
+
+@functools.partial(jax.jit, static_argnames=("inv_freq", "scale"))
+def _eager_rope(x, inv_freq, scale):
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None] * scale
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None] * scale
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
 @jax.jit

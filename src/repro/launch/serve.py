@@ -102,7 +102,7 @@ from .compile_cache import use_compile_cache
 TOKEN_BYTES = 4                    # int32 tokens in the slot arena
 MIN_SEQ_BUCKET = 8                 # smallest padded sequence bucket
 SERVED_KINDS = (OpKind.LINEAR, OpKind.MATMUL, OpKind.ATTENTION,
-                OpKind.DECODE_ATTENTION)
+                OpKind.DECODE_ATTENTION, OpKind.MOE)
 
 
 class ProvenanceError(RuntimeError):
@@ -651,7 +651,7 @@ class SolServer:
         x = packed.stage_batch(rows, self._device)     # ONE DMA
         self.stats["dmas"] += 1
         self.stats["forwards"] += 1
-        logits = self._fetch(self._run(("full", bb, sb), x))
+        logits, _ = self._fetch([self._run(("full", bb, sb), x)], None)
         self._bucket_stat(f"{bb}x{sb}")
         return [(r, logits[i, lens[i] - 1].copy())
                 for i, r in enumerate(batch)]
@@ -671,15 +671,14 @@ class SolServer:
         self.stats["prefill_real"] += real
         with telemetry.span("sol.prefill", bucket=f"{bb}x{sb}",
                             rids=_rids(reqs), real=real,
-                            padded=bb * sb - real):
+                            padded=bb * sb - real) as sp:
             with telemetry.span("sol.gather"):
                 rows = self._prompt_rows(reqs, bb, sb)
             x = packed.stage_batch(rows, self._device)     # ONE DMA
             self.stats["dmas"] += 1
             self.stats["forwards"] += 1
             # logits (bb, sb, vocab), then (k, v) rows (bb, sb, KV, hd)
-            logits, *kv = self._run(("prefill", bb, sb), x)
-            logits = self._fetch(logits)
+            logits, kv = self._fetch(self._run(("prefill", bb, sb), x), sp)
             self._write_kv(reqs, kv, [0] * len(reqs), lens)
             # copy: a bare slice would pin the whole step's logits tensor in
             # memory for as long as the request lives
@@ -702,7 +701,7 @@ class SolServer:
         self.stats["decode_real"] += len(reqs)
         with telemetry.span("sol.decode", bucket=f"{db}x{cb}",
                             rids=_rids(reqs), real=len(reqs),
-                            padded=db - len(reqs)):
+                            padded=db - len(reqs)) as sp:
             x = np.zeros((db, 1, self.cfg.d_model), np.float32)
             lens_arr = np.zeros((db,), np.int32)
             # a padding entry's slot id is out of range: it reads zeros
@@ -718,8 +717,8 @@ class SolServer:
             with telemetry.span("sol.gather"):
                 caches = self.arena.gather(slots, cb)
             # logits (db, 1, vocab), then one (k, v) row per request
-            logits, *kv = self._run(("decode", db, cb), x, lens_arr, *caches)
-            logits = self._fetch(logits)
+            logits, kv = self._fetch(
+                self._run(("decode", db, cb), x, lens_arr, *caches), sp)
             self._write_kv(reqs, kv, lens, [1] * len(reqs))
             results = [(r, logits[i, 0].copy()) for i, r in enumerate(reqs)]
         self._bucket_stat(f"d{db}x{cb}")
@@ -774,13 +773,26 @@ class SolServer:
             self.stats["compile_s"] += sp.t1 - sp.t0
             return out
 
-    def _fetch(self, logits) -> np.ndarray:
-        """Bring a bucket program's logits to the host; this waits for the
-        program to finish.  Its cache outputs stay on the device."""
-        nbytes = int(logits.nbytes)
+    def _fetch(self, outs: Sequence[Any], sp: Optional[telemetry.span]
+               ) -> Tuple[np.ndarray, List[Any]]:
+        """Split a prefill or decode program's outputs into the logits,
+        brought to the host (this waits for the program), and its cache
+        rows, which stay on the device.  A model with MOE layers returns
+        their counts last: they come down with the logits, and their sums
+        over the layers go on the forward's span ``sp`` as ``moe_pairs``
+        (pairs routed to a held expert) and ``moe_touched`` (held experts
+        that received any)."""
+        logits, *rest = outs
+        kv, counts = rest[:len(self._kv_row_shapes)], \
+            rest[len(self._kv_row_shapes):]
+        nbytes = sum(int(o.nbytes) for o in [logits] + counts)
         self.stats["d2h_bytes"] += nbytes
         with telemetry.span("sol.fetch", bytes=nbytes):
-            return np.asarray(logits)
+            logits, *counts = jax.device_get([logits] + counts)
+        for c in counts:
+            pairs, touched = np.asarray(c).sum(0)
+            sp.attrs.update(moe_pairs=int(pairs), moe_touched=int(touched))
+        return np.asarray(logits), kv
 
     def _bucket_stat(self, key: str) -> None:
         self.stats["buckets"][key] = self.stats["buckets"].get(key, 0) + 1

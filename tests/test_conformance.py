@@ -41,6 +41,7 @@ TOLERANCE = {
     "fused":      {"float32": (1e-5, 1e-5), "bfloat16": (3e-2, 3e-2)},
     "avgpool":    {"float32": (1e-5, 1e-5), "bfloat16": (3e-2, 3e-2)},
     "conv2d":     {"float32": (1e-5, 1e-5), "bfloat16": (3e-2, 3e-2)},
+    "moe":        {"float32": (1e-5, 1e-5), "bfloat16": (3e-2, 3e-2)},
 }
 
 _RNG = np.random.default_rng(0)
@@ -201,6 +202,30 @@ def _case_conv2d(dtype):
     return node, [x, w], ref
 
 
+def _case_moe(dtype):
+    """24 rows routed top-2 over 8 experts, experts 2..5 held here: one
+    held expert is never among a row's best (its router column is the
+    most negative for the positive rows), so it gets no rows, and pairs
+    routed to experts that are not held add nothing."""
+    from repro.kernels.moe.ref import held_counts, moe_dense, route
+    t, d, f, e, held, first = 24, 32, 48, 8, 4, 2
+    x = jnp.abs(_arr((t, d), "float32")).astype(dtype)
+    router = _arr((d, e), "float32")
+    router = router.at[:, first + 3].set(-jnp.abs(router).sum(1))
+    ws = [_arr((held, d, f), dtype, 0.2), _arr((held, d, f), dtype, 0.2),
+          _arr((held, f, d), dtype, 0.2)]
+    attrs = {"top_k": 2, "first": first}
+    _, touched = held_counts(route(x, router, top_k=2)[0], first, held)
+    assert int(touched) < held
+    node = Node(OpKind.MOE,
+                [ir.input_node((t, d), dtype), ir.input_node((d, e)),
+                 ir.input_node((held, d, f), dtype),
+                 ir.input_node((held, d, f), dtype),
+                 ir.input_node((held, f, d), dtype)],
+                TensorSpec((t, d), dtype), attrs=attrs)
+    return node, [x, router, *ws], moe_dense(x, router, *ws, **attrs)
+
+
 CASES = {
     "linear": _case_linear,
     "matmul": _case_matmul,
@@ -212,6 +237,7 @@ CASES = {
     "fused": _case_fused,
     "avgpool": _case_avgpool,
     "conv2d": _case_conv2d,
+    "moe": _case_moe,
 }
 
 
@@ -262,6 +288,7 @@ def test_matrix_covers_every_kernel_family():
         "rglru_scan": OpKind.RGLRU_SCAN,
         "rwkv6_scan": OpKind.RWKV6_SCAN, "fused": OpKind.FUSED,
         "avgpool": OpKind.AVGPOOL, "conv2d": OpKind.CONV2D,
+        "moe": OpKind.MOE,
     }
     assert set(case_kinds) == set(CASES)
     have = {op for (_b, op) in R._BACKEND_IMPLS} | set(R._SHARED_IMPLS)

@@ -3,7 +3,8 @@
 Nothing runs: each case lowers and compiles for one chip of a ``v5e:2x2``
 topology that the TPU compiler describes without a chip attached, at the
 widths of the served qwen2-1.5b-wide LM (d_model 1536, 12 heads of 128,
-MLP 6144, vocab 151,936).  A case passes when the TPU compiler accepts the
+MLP 6144, vocab 151,936), and the routed experts at Mellum2's (d_model
+2304, 16 held experts 896 wide, top 8 of 64).  A case passes when the TPU compiler accepts the
 program and a Pallas kernel (``tpu_custom_call``) is in it — what interpret
 mode on the CPU cannot show: block shapes off the (8, 128) tiling and
 kernels over their VMEM limit are refused here.
@@ -70,6 +71,27 @@ def _s(shape, dtype=jnp.float32):
 def test_matmul_compiles(one_chip, m, k, n):
     _compile(lambda x, w: matmul_call(x, w, block=(512, 256, 512)),
              one_chip, _s((m, k)), _s((k, n)))
+
+
+@pytest.mark.parametrize("rows", [8, 4096], ids=["decode8", "prefill8x512"])
+def test_moe_gmm_compiles(one_chip, rows):
+    """Every row tile the election may pin at the cell's largest decode
+    and prefill buckets, routing included, and the XLA candidate."""
+    from repro.kernels.moe.ops import moe, moe_tune_space
+    node = Node(OpKind.MOE, [input_node((rows, 2304)),
+                             input_node((2304, 64)),
+                             input_node((16, 2304, 896)),
+                             input_node((16, 2304, 896)),
+                             input_node((16, 896, 2304))],
+                TensorSpec((rows, 2304)), attrs={"top_k": 8})
+    from repro.kernels.moe.ref import moe_ref
+    shapes = (_s((rows, 2304)), _s((2304, 64)), _s((16, 2304, 896)),
+              _s((16, 2304, 896)), _s((16, 896, 2304)))
+    for (tm,) in moe_tune_space(node, None):
+        _compile(lambda x, r, g, u, dn: moe(x, r, g, u, dn, top_k=8, tm=tm),
+                 one_chip, *shapes)
+    # the XLA candidate: ragged_dot lowers to the TPU's own grouped matmul
+    _compile(lambda *a: moe_ref(*a, top_k=8), one_chip, *shapes)
 
 
 def test_flash_attention_compiles(one_chip):
