@@ -141,6 +141,12 @@ def _lower_node(n: Node, vals: List[Array], backend: "registry.Backend"
         return _lower_rope(n, vals[0], vals[1] if len(vals) > 1 else None)
     if op is OpKind.STACK:
         return jnp.stack(vals)
+    if op is OpKind.TAKE_ROWS:
+        # row idx[b] along axis 1 of each batch entry; an index out of
+        # range reads NaN ("fill"), never a silently clamped row
+        x, idx = vals
+        idx = idx.astype(jnp.int32).reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.take_along_axis(x, idx, axis=1, mode="fill")
     if op is OpKind.SOFTCAP:
         c = n.attrs["cap"]
         return jnp.tanh(vals[0] / c) * c
@@ -224,7 +230,7 @@ _REFERENCE_OPS = (
     list(_ELEMENTWISE)
     + [OpKind.ADD, OpKind.SUB, OpKind.MUL, OpKind.DIV, OpKind.BIAS_ADD,
        OpKind.SCALE, OpKind.SQRT, OpKind.TIME_SHIFT, OpKind.ROPE,
-       OpKind.STACK, OpKind.SOFTCAP,
+       OpKind.STACK, OpKind.TAKE_ROWS, OpKind.SOFTCAP,
        OpKind.MAXPOOL, OpKind.AVGPOOL,
        OpKind.GLOBALPOOL, OpKind.LAYERNORM, OpKind.RMSNORM, OpKind.BATCHNORM,
        OpKind.SOFTMAX, OpKind.DROPOUT, OpKind.FLATTEN, OpKind.RESHAPE,

@@ -110,6 +110,7 @@ class OpKind(enum.Enum):
     ROPE = "rope"             # rotary position embedding of (B, S, H, hd)
     MOE_COUNT = "moe_count"   # int32 (pairs, touched) of one MOE node
     STACK = "stack"           # stack same-shape inputs on a new axis 0
+    TAKE_ROWS = "take_rows"   # row idx[b] of (B, S, D) per entry: (B, 1, D)
     ADD = "add"
     SUB = "sub"
     MUL = "mul"

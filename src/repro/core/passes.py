@@ -320,6 +320,11 @@ def _node_cost_terms(n: Node) -> Tuple[float, float, float]:
         rows = n.spec.size // d
         pairs = rows * n.attrs["top_k"] * n_held / n.inputs[1].spec.shape[-1]
         return 6.0 * pairs * d * f, streamed, streamed
+    if n.op is OpKind.TAKE_ROWS:
+        # a gather: the selected rows read once and written once, and the
+        # indices; the rows left behind are never touched
+        moved = 2.0 * out_bytes + n.inputs[1].spec.size * 4.0
+        return 0.0, moved, moved
     if n.op is OpKind.RGLRU_SCAN:
         # h_t = a·h + b: ~2 FLOPs/element; streamed bytes dominate either way
         return 2.0 * n.spec.size, streamed, streamed

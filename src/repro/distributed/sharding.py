@@ -452,6 +452,20 @@ def shard_graph(g, mesh: Mesh):
             if ent(n.inputs[0], 1) is not None:
                 raise ShardingError(f"{n.name}: shift along a sharded axis")
             spec[id(n)] = pspec(n.inputs[0])
+        elif op is OpKind.TAKE_ROWS:
+            # each batch entry's row is picked along the sequence axis: the
+            # batch may be data-sharded, the rows and features are whole,
+            # and every shard holds the indices of its own entries
+            x, idx = n.inputs
+            if any(ent(x, i) is not None for i in range(1, rank)):
+                raise ShardingError(
+                    f"{n.name}: row selection over a sharded sequence or "
+                    f"feature axis")
+            if ent(idx, 0) != ent(x, 0):
+                raise ShardingError(
+                    f"{n.name}: indices sharded as {pspec(idx)}, the batch "
+                    f"as {pspec(x)}")
+            spec[id(n)] = P(ent(x, 0), *([None] * (rank - 1)))
         elif op in (OpKind.ADD, OpKind.SUB, OpKind.MUL, OpKind.DIV):
             out: List[Any] = []
             for i in range(rank):

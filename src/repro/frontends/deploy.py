@@ -73,14 +73,17 @@ def deploy(sol_model: SolModel,
                                         jnp.dtype(i.spec.dtype))
                    for i in g.inputs]
     return export_fn(sol_model._fn, sol_model._params_for_call(),
-                     *x_specs, elections=elections)
+                     *x_specs, elections=elections,
+                     head_rows=sol_model.head_rows)
 
 
 def export_fn(fn, params, *x_specs: jax.ShapeDtypeStruct,
-              elections: Optional[Dict[str, Any]] = None) -> bytes:
+              elections: Optional[Dict[str, Any]] = None,
+              head_rows: Optional[int] = None) -> bytes:
     """Export ``fn(params, *xs)`` plus ``params`` — any (possibly nested)
     dict pytree of arrays — into the artifact format.  ``deploy`` is the
-    SolModel front door; this is the general entry point."""
+    SolModel front door; this is the general entry point.  ``head_rows``
+    is a serving prefill program's (``SolModel.head_rows``)."""
     p_spec = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(np.shape(a), np.asarray(a).dtype),
         params)
@@ -94,7 +97,7 @@ def export_fn(fn, params, *x_specs: jax.ShapeDtypeStruct,
         for i, arr in enumerate(leaves):
             z.writestr(f"params/{i}.npy", _npy_bytes(arr))
         manifest = {"schema": MANIFEST_SCHEMA, "tree": tree,
-                    "elections": elections or {}}
+                    "elections": elections or {}, "head_rows": head_rows}
         z.writestr("manifest.json", json.dumps(manifest))
     return buf.getvalue()
 
@@ -151,6 +154,7 @@ class DeployedModel:
         self.params = _tree_build(manifest["tree"], staged)
         self.staged_leaves = len(staged)
         self._elections = manifest.get("elections") or {}
+        self.head_rows = manifest.get("head_rows")
         self._call = exp.call
 
     def __call__(self, *xs) -> Any:

@@ -17,7 +17,7 @@ keeps sharing the framework's parameter storage (paper Listing 2:
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Tuple, Type
+from typing import Callable, Dict, List, Optional, Tuple, Type
 
 import numpy as np
 
@@ -198,9 +198,12 @@ class EmitContext:
 
 @register_emitter(nn.Sequential)
 def _emit_sequential(m: nn.Sequential, ctx: EmitContext, x: Node,
-                     path: str) -> Node:
+                     path: str, start: int = 0,
+                     stop: Optional[int] = None) -> Node:
+    """Children ``[start, stop)`` of the chain in order, each under its
+    index in ``m`` (the parameter path)."""
     cur = x
-    for idx, child in enumerate(m):
+    for idx, child in list(enumerate(m))[start:stop]:
         cur = ctx.emit(child, cur, f"{path}{idx}.")
     return cur
 
@@ -512,20 +515,66 @@ def _counts(ctx: EmitContext) -> List[Node]:
                  TensorSpec((len(ctx.counters), 2), "int32"))]
 
 
+def _sequence_dependent(m: nn.Module) -> bool:
+    """Whether ``m`` or any module below it mixes sequence positions."""
+    return (isinstance(m, tuple(_SEQUENCE_MODULES))
+            or any(_sequence_dependent(c) for c in m._children.values()))
+
+
+def position_wise_suffix(model: nn.Module) -> int:
+    """How many trailing children of a top-level ``Sequential`` chain hold
+    no sequence-dependent module anywhere in their subtrees: each of their
+    output rows depends on its own input row alone, so the prefill program
+    runs them on the rows it keeps.  0 for any other model."""
+    if _emitter_for(model) is not _emit_sequential:
+        return 0
+    n = 0
+    for child in reversed(list(model)):
+        if _sequence_dependent(child):
+            break
+        n += 1
+    return n
+
+
+def _take_rows(x: Node, idx: Node) -> Node:
+    b, _, *rest = x.spec.shape
+    return Node(OpKind.TAKE_ROWS, [x, idx],
+                TensorSpec((b, 1, *rest), x.spec.dtype))
+
+
 def extract_prefill(model: nn.Module, input_shape: Tuple[int, ...],
                     dtype: str = "float32") -> Graph:
-    """The serving prefill program: identical compute to :func:`extract`,
-    but every attention layer's (k, v) projections join the graph outputs —
-    ``outputs = [logits, k_0, v_0, k_1, v_1, ...]`` in layer order — so one
-    prompt forward both produces next-token logits and seeds the request's
-    KV-cache slot.  A model with MOE layers adds their counts last
-    (:func:`_counts`)."""
+    """The serving prefill program: the compute of :func:`extract` for the
+    rows that are kept, and every attention layer's (k, v) projections as
+    outputs, so one prompt forward both produces next-token logits and
+    seeds the request's KV-cache slot.
+
+    ``inputs  = [x (B, S, D), last (B,) int32]``
+    ``outputs = [logits (B, 1, V), k_0, v_0, k_1, v_1, ...]``, and the MOE
+    layers' counts last where the model has any (:func:`_counts`).
+
+    ``last[b]`` is the position whose logits entry ``b`` returns, a traced
+    input, so one program serves every prompt length of its bucket.  The
+    row is taken (``TAKE_ROWS``) before the :func:`position_wise_suffix`
+    (the head, and a final norm before it), which then runs on ``B`` rows,
+    not ``B·S``; a model with no such suffix has its output's rows taken.
+    ``graph.head_rows`` records which: ``B`` or ``B·S``."""
     x = ir.input_node(input_shape, dtype, ir.BSD(), name="input")
+    last = ir.input_node(input_shape[:1], "int32", name="last")
     ctx = EmitContext(dtype, mode="prefill")
-    out = ctx.emit(model, x, "")
-    g = Graph(inputs=[x], outputs=[out] + ctx.kv_outputs + _counts(ctx),
+    n = position_wise_suffix(model)
+    if n:
+        k = len(list(model)) - n
+        rows = _take_rows(_emit_sequential(model, ctx, x, "", stop=k), last)
+        out = _emit_sequential(model, ctx, rows, "", start=k)
+    else:
+        out = _take_rows(ctx.emit(model, x, ""), last)
+    g = Graph(inputs=[x, last], outputs=[out] + ctx.kv_outputs + _counts(ctx),
               params=ctx.params)
     g.validate()
+    # rows the head ran on, as the server reports them
+    b, s = input_shape[:2]
+    g.head_rows = b if n else b * s
     return g
 
 

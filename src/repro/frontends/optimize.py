@@ -60,6 +60,12 @@ class SolModel(nn.Module):
             self._ctx_version = v
         return self._ctx_params
 
+    @property
+    def head_rows(self) -> Optional[int]:
+        """Rows a serving prefill program's head runs on
+        (``extract_prefill``); None for any other graph."""
+        return getattr(self.graph, "head_rows", None)
+
     def load_state_dict(self, sd: Dict[str, Any]) -> None:
         self._source.load_state_dict(sd)
 

@@ -15,7 +15,9 @@ Two serving programs (``ServeConfig.decode=True``, the default):
 * **prefill** (``frontends.extract.extract_prefill``) — one forward over
   the whole prompt; every attention layer's (k, v) projections join the
   graph outputs so the same forward that produces the first token also
-  seeds the request's KV-cache slot.
+  seeds the request's KV-cache slot.  Each entry's last position is an
+  int32 input: the program keeps that row before the head and returns
+  ``(B, 1, V)`` logits, one program per bucket for every prompt length.
 * **decode** (``frontends.extract.extract_decode``) — one token per
   resident request against the cached keys/values through the
   ``DECODE_ATTENTION`` op: inputs are the last token's embedding
@@ -47,11 +49,10 @@ Pieces, and which paper mechanism each reproduces:
   bucket, so every served shape (including every ``DECODE_ATTENTION``
   cache bucket) hits the measured-timing entries and pinned ``Tunable``
   configs exactly, never the roofline fallback.
-* **Packed staging** — each prefill forward's embedded rows go
-  host→device as ONE DMA via ``runtime.packed.stage_batch``; each decode
-  forward's mixed inputs (token rows, int32 lengths, int32 slot ids) go as
-  ONE DMA via ``runtime.packed.stage_inputs`` (the VEO-udma gather
-  policy).  Only the logits come back.
+* **Packed staging** — each forward's mixed inputs go host→device as ONE
+  DMA via ``runtime.packed.stage_inputs`` (the VEO-udma gather policy):
+  a prefill's embedded rows and last positions, a decode's token rows,
+  int32 lengths and slot ids.  Only one logits row per entry comes back.
 * **Continuous batching** — the scheduler serves the least-recently-served
   ``max_batch`` residents each step (starvation-free round-robin), then
   partitions them: freshly admitted requests run the prefill program,
@@ -533,7 +534,8 @@ class SolServer:
         self._t0: Optional[float] = None
         self._t_last: Optional[float] = None
         # prefill_positions / prefill_real: bucket positions (batch x seq)
-        # against prompt tokens; decode_rows / decode_real: bucket rows
+        # against prompt tokens; prefill_head_rows: rows the prefill's
+        # position-wise suffix ran on; decode_rows / decode_real: bucket rows
         # against residents served; d2h_bytes: program outputs brought to
         # the host (the logits); kv_rows_written: cache positions written on
         # the device (each in every cached tensor); kv_host_bytes: cache
@@ -544,6 +546,7 @@ class SolServer:
                       "prefills": 0, "decodes": 0, "admitted": 0,
                       "evicted": 0, "buckets": {},
                       "prefill_positions": 0, "prefill_real": 0,
+                      "prefill_head_rows": 0,
                       "decode_rows": 0, "decode_real": 0, "d2h_bytes": 0,
                       "kv_rows_written": 0, "kv_host_bytes": 0,
                       "compiles": 0, "compile_s": 0.0}
@@ -648,7 +651,7 @@ class SolServer:
         bb, sb = self._bucket(len(batch), max(lens))
         with telemetry.span("sol.gather"):
             rows = self._prompt_rows(batch, bb, sb)
-        x = packed.stage_batch(rows, self._device)     # ONE DMA
+        x = packed.stage_batch(list(rows), self._device)     # ONE DMA
         self.stats["dmas"] += 1
         self.stats["forwards"] += 1
         logits, _ = self._fetch([self._run(("full", bb, sb), x)], None)
@@ -661,7 +664,9 @@ class SolServer:
         """Prompt forward through the prefill program: produces the first
         token's logits AND the (k, v) rows that seed each request's KV
         slot — rows ``[0, L)`` of every cached tensor, written on the
-        device from the program's outputs."""
+        device from the program's outputs.  The program takes each entry's
+        last position as an input and returns that row's logits alone, so
+        every prompt length of a bucket runs the bucket's one program."""
         if not reqs:
             return []
         lens = [r.length for r in reqs]
@@ -673,17 +678,23 @@ class SolServer:
                             rids=_rids(reqs), real=real,
                             padded=bb * sb - real) as sp:
             with telemetry.span("sol.gather"):
-                rows = self._prompt_rows(reqs, bb, sb)
-            x = packed.stage_batch(rows, self._device)     # ONE DMA
+                x = self._prompt_rows(reqs, bb, sb)
+                last = np.zeros((bb,), np.int32)   # padding entries: row 0
+                last[:len(reqs)] = np.asarray(lens) - 1
+            x, last = packed.stage_inputs([x, last], self._device)  # ONE DMA
             self.stats["dmas"] += 1
             self.stats["forwards"] += 1
-            # logits (bb, sb, vocab), then (k, v) rows (bb, sb, KV, hd)
-            logits, kv = self._fetch(self._run(("prefill", bb, sb), x), sp)
+            # logits (bb, 1, vocab), then (k, v) rows (bb, sb, KV, hd)
+            key = ("prefill", bb, sb)
+            logits, kv = self._fetch(self._run(key, x, last), sp)
+            # rows the head ran on, as the program records it
+            head_rows = self._models[key].head_rows
+            sp.attrs["head_rows"] = head_rows
+            self.stats["prefill_head_rows"] += head_rows
             self._write_kv(reqs, kv, [0] * len(reqs), lens)
-            # copy: a bare slice would pin the whole step's logits tensor in
-            # memory for as long as the request lives
-            results = [(r, logits[i, lens[i] - 1].copy())
-                       for i, r in enumerate(reqs)]
+            # copy: a bare slice would pin the step's logits for as long
+            # as the request lives
+            results = [(r, logits[i, 0].copy()) for i, r in enumerate(reqs)]
         self._bucket_stat(f"{bb}x{sb}")
         return results
 
@@ -744,17 +755,16 @@ class SolServer:
                 self.stats["kv_rows_written"] += counts[i]
 
     def _prompt_rows(self, reqs: List[Request], bb: int, sb: int
-                     ) -> List[np.ndarray]:
-        """Each request's context from its arena slot, embedded and padded
-        to ``sb`` rows, plus zero rows up to the batch bucket ``bb``."""
-        rows = []
-        for r in reqs:
+                     ) -> np.ndarray:
+        """``(bb, sb, d_model)`` f32: each request's context from its arena
+        slot, embedded and padded to ``sb`` rows, then zero entries up to
+        the batch bucket ``bb``."""
+        rows = np.zeros((bb, sb, self.cfg.d_model), np.float32)
+        for i, r in enumerate(reqs):
             padded = np.zeros(sb, np.int32)
             t = self.arena.tokens(r.slot)
             padded[: len(t)] = t
-            rows.append(self.embed[padded])            # (sb, d_model) f32
-        for _ in range(bb - len(reqs)):
-            rows.append(np.zeros((sb, self.cfg.d_model), np.float32))
+            np.take(self.embed, padded, axis=0, out=rows[i])
         return rows
 
     def _run(self, key: Tuple, *args):

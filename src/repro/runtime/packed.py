@@ -54,13 +54,18 @@ def replicated(mesh) -> Any:
 
 @dataclasses.dataclass
 class PackedTransfer:
-    buffer: jax.Array                  # packed uint8 staging buffer
+    buffer: jax.Array                  # packed staging buffer, uint words
     layout: List[Tuple[Tuple[int, ...], str, int]]  # (shape, dtype, offset)
 
 
 def pack_transfer(arrays: Sequence[np.ndarray],
                   device=None) -> PackedTransfer:
-    """Pack many host arrays into one device transfer."""
+    """Pack many host arrays into one device transfer.  The buffer's words
+    are as wide as the narrowest array's items, at most 32 bits: a TPU
+    unpacks a 32-bit buffer of 32-bit arrays with same-width bitcasts,
+    where a byte buffer needs a 4-wide minor dimension that the compiler
+    pads to 128 lanes (and, for some layouts of a few MB, compiles for
+    minutes)."""
     layout: List[Tuple[Tuple[int, ...], str, int]] = []
     total = 0
     aligned: List[np.ndarray] = []
@@ -70,12 +75,13 @@ def pack_transfer(arrays: Sequence[np.ndarray],
         layout.append((tuple(a.shape), str(a.dtype), off))
         total = off + a.nbytes
         aligned.append(a)
+    word = min([4] + [a.itemsize for a in aligned])
     with span("sol.stage.pack"):
-        buf = np.zeros(total, np.uint8)
+        buf = np.zeros(-(-total // word) * word, np.uint8)
         for a, (_, _, off) in zip(aligned, layout):
             buf[off:off + a.nbytes] = a.view(np.uint8).reshape(-1)
     with span("sol.stage.put"):
-        dev_buf = jax.device_put(buf, device)
+        dev_buf = jax.device_put(buf.view(f"uint{8 * word}"), device)
     return PackedTransfer(dev_buf, layout)
 
 
@@ -90,18 +96,20 @@ def unpack_on_device(pt: PackedTransfer) -> List[jax.Array]:
 @functools.lru_cache(maxsize=512)
 def _unpack_jit(layout: Tuple[Tuple[Tuple[int, ...], str, int], ...]):
     def f(buf):
+        word = buf.dtype.itemsize
         out = []
         with jax.named_scope("sol.unpack"):
             for shape, dtype, off in layout:
                 item = np.dtype(dtype).itemsize
-                n = int(np.prod(shape)) * item
+                n = int(np.prod(shape)) * item // word
                 if n == 0:
                     out.append(jnp.zeros(shape, dtype))
                     continue
-                chunk = jax.lax.dynamic_slice(buf, (off,), (n,))
-                # bitcast uint8 → dtype folds the trailing itemsize dim
-                arr = jax.lax.bitcast_convert_type(
-                    chunk.reshape(-1, item), jnp.dtype(dtype))
+                chunk = jax.lax.dynamic_slice(buf, (off // word,), (n,))
+                if item > word:
+                    # bitcast folds the trailing (item / word) dim
+                    chunk = chunk.reshape(-1, item // word)
+                arr = jax.lax.bitcast_convert_type(chunk, jnp.dtype(dtype))
                 out.append(arr.reshape(shape))
         return out
     return jax.jit(f)

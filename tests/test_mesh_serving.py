@@ -56,9 +56,12 @@ d = float(np.max(np.abs(np.asarray(ref) - np.asarray(shr))))
 assert d < 1e-5, f"forward diverged: {d}"
 print("FORWARD PARITY OK", d)
 
-# prefill: logits AND the kv rows that seed the cache slots
-ref = compile_graph(m, extract_prefill(m, (2, 8, 32)), "xla")(x)
-shr = compile_graph(m, extract_prefill(m, (2, 8, 32)), "xla", mesh=mesh)(x)
+# prefill: each entry's last-position logits AND the kv rows that seed the
+# cache slots; the positions are data-sharded with the batch
+last = np.array([7, 3], np.int32)
+ref = compile_graph(m, extract_prefill(m, (2, 8, 32)), "xla")(x, last)
+shr = compile_graph(m, extract_prefill(m, (2, 8, 32)), "xla",
+                    mesh=mesh)(x, last)
 for i, (a, b) in enumerate(zip(ref, shr)):
     d = float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
     assert d < 1e-5, f"prefill out[{i}] diverged: {d}"

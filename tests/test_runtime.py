@@ -144,6 +144,18 @@ def test_packed_transfer_roundtrip(shapes, dtype, seed):
         np.testing.assert_array_equal(np.asarray(o), a)
 
 
+def test_packed_buffer_words_follow_the_narrowest_item():
+    """32-bit arrays travel in a uint32 buffer, unpacked by same-width
+    bitcasts; a byte array among them makes the buffer bytes."""
+    wide = [np.ones((3, 5), np.float32), np.arange(3, dtype=np.int32)]
+    assert pack_transfer(wide).buffer.dtype == np.uint32
+    assert pack_transfer(wide + [np.ones(3, np.uint8)]).buffer.dtype \
+        == np.uint8
+    out = unpack_on_device(pack_transfer(wide))
+    for a, o in zip(wide, out):
+        np.testing.assert_array_equal(np.asarray(o), a)
+
+
 def test_packed_alignment():
     arrays = [np.ones(3, np.uint8), np.ones(5, np.float32)]
     pt = pack_transfer(arrays)

@@ -229,6 +229,9 @@ def test_deploy_serve_roundtrip():
     for bucket in arts:
         assert (replay._models[bucket].impl_report(by_kind=True)
                 == live._models[bucket].impl_report(by_kind=True))
+    # and so does the count of rows each prefill program's head ran on
+    assert replay.stats["prefill_head_rows"] == live.stats[
+        "prefill_head_rows"] > 0
     # a bucket without an artifact is loud, never a silent live compile
     with pytest.raises(KeyError, match="deploy"):
         replay._model_for((8, 8))
@@ -382,10 +385,12 @@ def test_decode_input_size_is_cache_bucket_not_history():
         server.run()
     finally:
         packed.stage_inputs = orig
-    # first token came from prefill; the other 11 are one decode DMA each
-    assert len(sizes) == 11
+    # first token came from prefill (its rows and last position, one DMA);
+    # the other 11 are one decode DMA each
+    assert len(sizes) == 12
+    decode = sizes[1:]
     # within one cache bucket the staged bytes are constant
-    assert len(set(sizes[:4])) == 1, sizes      # cache lens 3..6 → cb 8
+    assert len(set(decode[:4])) == 1, sizes     # cache lens 3..6 → cb 8
     server.close()
 
 
